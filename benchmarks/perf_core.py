@@ -15,10 +15,6 @@ from __future__ import annotations
 
 import argparse
 import json
-import os
-import pathlib
-import subprocess
-import sys
 import time
 
 import jax
@@ -28,6 +24,7 @@ jax.config.update("jax_enable_x64", True)
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import (log_speedup, plan_classes, power,
                         sample_class_workloads, sample_workloads,
                         shifted_power, simulate_ensemble,
@@ -402,23 +399,16 @@ def bench_robust(quick: bool = False):
 FLEET_DEVICE_COUNTS = (1, 2, 4, 8)
 
 
-def bench_fleet_worker(devices: int, base_n: int, quick: bool) -> list:
-    """Measure sharded planning/simulation on THIS process's devices.
+def bench_fleet_at(devices: int, base_n: int, quick: bool) -> list:
+    """Sharded planning/simulation over the first ``devices`` devices.
 
-    Runs inside a subprocess whose XLA_FLAGS forced ``devices`` host
-    devices (the flag must be set before jax initializes, hence the
-    process boundary).  Weak scaling: the per-device load is fixed at
-    ``base_n`` instances, so N = base_n · D and ideal instances/sec
-    grows linearly with D.
+    Weak scaling: the per-device load is fixed at ``base_n`` instances,
+    so N = base_n · D and ideal instances/sec grows linearly with D.
     """
     from repro.distributed import (fleet_mesh, plan_sharded,
                                    simulate_ensemble_sharded)
 
-    if len(jax.devices()) != devices:
-        raise RuntimeError(
-            f"fleet worker expected {devices} devices, found "
-            f"{len(jax.devices())} — XLA_FLAGS not applied?")
-    mesh = fleet_mesh()
+    mesh = fleet_mesh(devices)
     N, M = base_n * devices, 16
     sp = _SPS["regular"]
     wl = sample_workloads(0, K=N, M=M, B=B, m_range=(max(2, M // 2), M))
@@ -460,37 +450,29 @@ def bench_fleet_worker(devices: int, base_n: int, quick: bool) -> list:
 
 
 def bench_fleet(quick: bool = False):
-    """Weak-scaling rows: sharded instances/sec at 1/2/4/8 host devices.
+    """Weak-scaling rows at 1/2/4/8 devices, in this process.
 
-    Each device count runs in its own subprocess because
-    ``--xla_force_host_platform_device_count`` only takes effect before
-    jax initializes; workers report rows back as JSON on stdout.
+    Each count D runs over the first D devices this process sees.  On
+    the CPU set ``XLA_FLAGS=--xla_force_host_platform_device_count=8``
+    before starting; a count above what the process sees raises, so a
+    regression gate never loses a row without a word.
     """
+    require_devices(max(FLEET_DEVICE_COUNTS), "the fleet sweep")
     base_n = 32 if quick else 64
-    repo = pathlib.Path(__file__).resolve().parent.parent
     rows = []
     for D in FLEET_DEVICE_COUNTS:
-        env = dict(os.environ)
-        flags = env.get("XLA_FLAGS", "")
-        env["XLA_FLAGS"] = (
-            f"{flags} --xla_force_host_platform_device_count={D}").strip()
-        # the forced device count only applies to the CPU backend — on a
-        # GPU/TPU host the worker would otherwise come up with the
-        # accelerator's device count and hard-fail its sanity check
-        env["JAX_PLATFORMS"] = "cpu"
-        env["PYTHONPATH"] = (str(repo / "src") + os.pathsep
-                             + env.get("PYTHONPATH", "")).rstrip(os.pathsep)
-        cmd = [sys.executable, "-m", "benchmarks.perf_core",
-               "--fleet-worker", str(D), "--fleet-base-n", str(base_n)]
-        if quick:
-            cmd.append("--quick")
-        out = subprocess.run(cmd, env=env, cwd=repo, capture_output=True,
-                             text=True)
-        if out.returncode != 0:
-            raise RuntimeError(
-                f"fleet worker D={D} failed:\n{out.stderr[-2000:]}")
-        rows.extend(json.loads(out.stdout.strip().splitlines()[-1]))
+        rows.extend(bench_fleet_at(D, base_n, quick))
     return rows
+
+
+def require_devices(n: int, what: str) -> None:
+    """Raise unless this process sees at least ``n`` devices."""
+    have = len(jax.devices())
+    if have < n:
+        raise RuntimeError(
+            f"{what} needs {n} devices and this process sees {have}; on "
+            f"the CPU start it with XLA_FLAGS="
+            f"--xla_force_host_platform_device_count={n}")
 
 
 def collect(quick: bool = False):
@@ -617,15 +599,8 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true")
     ap.add_argument("--out", default="BENCH_core.json")
-    ap.add_argument("--fleet-worker", type=int, default=None,
-                    help="internal: measure sharded rows on this many "
-                         "forced host devices and print them as JSON")
-    ap.add_argument("--fleet-base-n", type=int, default=64)
     args = ap.parse_args()
-    if args.fleet_worker is not None:
-        print(json.dumps(bench_fleet_worker(args.fleet_worker,
-                                            args.fleet_base_n, args.quick)))
-        return
+    enable_compile_cache()
     report = collect(quick=args.quick)
     with open(args.out, "w") as f:
         json.dump(report, f, indent=2)

@@ -14,9 +14,9 @@ Five questions, one JSON:
 
   * **Multi-tenant sharding** — ``serve_multitenant_*_T{1,2,4,8}``
     runs T independent tenant streams through
-    ``serve_streams_sharded`` on T forced host devices (each count in
-    its own subprocess, like perf_core's fleet rows) with fixed
-    per-tenant load — ideal weak scaling is T× the T=1 events/sec.
+    ``serve_streams_sharded`` over the first T devices of this
+    process with fixed per-tenant load — ideal weak scaling is T× the
+    T=1 events/sec.
 
   * **Trace replay** — ``serve_trace_replay`` replays the recorded
     arrival log under ``benchmarks/traces/`` through the controller
@@ -42,10 +42,7 @@ from __future__ import annotations
 
 import argparse
 import json
-import os
 import pathlib
-import subprocess
-import sys
 import time
 
 import jax
@@ -55,6 +52,8 @@ jax.config.update("jax_enable_x64", True)
 import jax.numpy as jnp
 import numpy as np
 
+from benchmarks.perf_core import require_devices
+from repro.compile_cache import enable_compile_cache
 from repro.core import power, sample_arrival_stream, sample_workloads
 from repro.core.workloads import load_arrival_log
 from repro.sched.policies import StreamingSmartFillPolicy
@@ -167,28 +166,18 @@ def bench_stream(quick: bool = False):
 MULTITENANT_COUNTS = (1, 2, 4, 8)
 
 
-def bench_multitenant_worker(tenants: int, quick: bool) -> list:
-    """Measure the sharded multi-tenant serve on THIS process's devices.
-
-    Runs inside a subprocess whose XLA_FLAGS forced ``tenants`` host
-    devices (one tenant per device).  Weak scaling: per-tenant load is
-    fixed, so ideal total events/sec grows linearly with T — on runners
-    with fewer physical cores than T the curve flattens at the core
-    count, which is why the regression gate scopes these rows with
-    ``--min-devices`` (see check_regression.py).
-    """
+def bench_multitenant_at(tenants: int, quick: bool) -> list:
+    """The sharded multi-tenant serve over the first ``tenants`` devices,
+    one tenant per device.  Weak scaling: per-tenant load is fixed, so
+    ideal total events/sec grows linearly with T."""
     from repro.distributed import fleet_mesh, serve_streams_sharded
 
-    if len(jax.devices()) != tenants:
-        raise RuntimeError(
-            f"multitenant worker expected {tenants} devices, found "
-            f"{len(jax.devices())} — XLA_FLAGS not applied?")
     horizon = 1_800.0 if quick else 7_200.0
     streams = [sample_arrival_stream(
         17 + i, horizon=horizon, rate=0.12, diurnal=0.75, period=horizon,
         B=B, n_budget_events=2, budget_frac=(0.3, 0.8),
         deadline_slack=50.0) for i in range(tenants)]
-    mesh = fleet_mesh()
+    mesh = fleet_mesh(tenants)
 
     def run():
         return serve_streams_sharded(SP, streams, max_live=8, mesh=mesh)
@@ -216,31 +205,16 @@ def bench_multitenant_worker(tenants: int, quick: bool) -> list:
 
 
 def bench_multitenant(quick: bool = False):
-    """Weak-scaling rows: sharded tenants at 1/2/4/8 forced host devices.
+    """Weak-scaling rows at 1/2/4/8 tenants, in this process.
 
-    Each tenant count runs in its own subprocess because
-    ``--xla_force_host_platform_device_count`` only takes effect before
-    jax initializes (same pattern as perf_core.bench_fleet)."""
-    repo = pathlib.Path(__file__).resolve().parent.parent
+    Each count T runs over the first T devices this process sees (see
+    perf_core.bench_fleet: on the CPU set
+    ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` before
+    starting; a count above what the process sees raises)."""
+    require_devices(max(MULTITENANT_COUNTS), "the multi-tenant sweep")
     rows = []
     for T in MULTITENANT_COUNTS:
-        env = dict(os.environ)
-        flags = env.get("XLA_FLAGS", "")
-        env["XLA_FLAGS"] = (
-            f"{flags} --xla_force_host_platform_device_count={T}").strip()
-        env["JAX_PLATFORMS"] = "cpu"
-        env["PYTHONPATH"] = (str(repo / "src") + os.pathsep
-                             + env.get("PYTHONPATH", "")).rstrip(os.pathsep)
-        cmd = [sys.executable, "-m", "benchmarks.perf_serve",
-               "--multitenant-worker", str(T)]
-        if quick:
-            cmd.append("--quick")
-        out = subprocess.run(cmd, env=env, cwd=repo, capture_output=True,
-                             text=True)
-        if out.returncode != 0:
-            raise RuntimeError(
-                f"multitenant worker T={T} failed:\n{out.stderr[-2000:]}")
-        rows.extend(json.loads(out.stdout.strip().splitlines()[-1]))
+        rows.extend(bench_multitenant_at(T, quick))
     return rows
 
 
@@ -401,14 +375,8 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true")
     ap.add_argument("--out", default="BENCH_serve.json")
-    ap.add_argument("--multitenant-worker", type=int, default=None,
-                    help="internal: emit serve_multitenant rows for this "
-                         "process's forced device count as JSON on stdout")
     args = ap.parse_args()
-    if args.multitenant_worker is not None:
-        print(json.dumps(bench_multitenant_worker(args.multitenant_worker,
-                                                  args.quick)))
-        return
+    enable_compile_cache()
     report = collect(quick=args.quick)
     with open(args.out, "w") as f:
         json.dump(report, f, indent=2)

@@ -56,6 +56,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import fmath
+
 from .smartfill import (HeteroSmartFillSchedule, _permute_speedup,
                         smartfill_hetero)
 from .speedup import RegularSpeedup, Speedup, StackedSpeedup, is_per_job
@@ -172,13 +174,13 @@ def class_speedup(sp: Speedup, counts) -> Speedup:
     if isinstance(sp, RegularSpeedup):
         gamma = jnp.broadcast_to(jnp.asarray(sp.gamma, n.dtype), n.shape)
         return RegularSpeedup(
-            A=jnp.asarray(sp.A, n.dtype) * n ** (-gamma),
+            A=jnp.asarray(sp.A, n.dtype) * fmath.power(n, -gamma),
             w=jnp.asarray(sp.w, n.dtype) * n,
             gamma=gamma, sigma=sp.sigma, B=sp.B)
     if isinstance(sp, StackedSpeedup):
         gamma = jnp.broadcast_to(jnp.asarray(sp.gamma, n.dtype), n.shape)
         return StackedSpeedup(
-            A=jnp.asarray(sp.A, n.dtype) * n ** (-gamma),
+            A=jnp.asarray(sp.A, n.dtype) * fmath.power(n, -gamma),
             w=jnp.asarray(sp.w, n.dtype) * n,
             gamma=gamma,
             sigma=jnp.broadcast_to(jnp.asarray(sp.sigma, n.dtype), n.shape),

@@ -70,6 +70,8 @@ import typing
 import jax
 import jax.numpy as jnp
 
+from . import fmath
+
 from .speedup import RegularSpeedup, Speedup, StackedSpeedup, is_per_job
 
 __all__ = [
@@ -311,7 +313,7 @@ def solve_cap_generic(sp: Speedup, b, c, active=None, iters: int = 96,
     def shrink(carry):
         lo, hi = carry
         # bisect in log-space for relative precision across wide λ ranges
-        mid = jnp.exp(0.5 * (jnp.log(lo) + jnp.log(hi)))
+        mid = fmath.exp(0.5 * (fmath.log(lo) + fmath.log(hi)))
         beta = jnp.sum(theta_of(mid))
         # β decreasing in λ: β > b ⇒ λ* right of mid
         lo = jnp.where(beta > b_safe, mid, lo)
@@ -336,7 +338,7 @@ def solve_cap_generic(sp: Speedup, b, c, active=None, iters: int = 96,
 
         _, lo, hi = jax.lax.while_loop(cond, body, (0, lam_lo, lam_hi))
 
-    lam = jnp.exp(0.5 * (jnp.log(lo) + jnp.log(hi)))
+    lam = fmath.exp(0.5 * (fmath.log(lo) + fmath.log(hi)))
     theta = theta_of(lam)
     # exact budget: rescale the fp residual onto the positive allocations
     tot = jnp.sum(theta)
@@ -451,16 +453,17 @@ def _hetero_coeffs(A, w, gamma, sigma, c, act):
     """(P, E, Q) of the uncapped curve plus λ_act per job (0 inactive)."""
     c_safe = jnp.where(act, c, 1.0)
     E = 1.0 / gamma
-    P = sigma * (c_safe / A) ** E
+    P = sigma * fmath.power(c_safe / A, E)
     Q = sigma * w
-    ds0 = jnp.where(w > 0, A * jnp.maximum(w, 1e-300) ** gamma, jnp.inf)
+    ds0 = jnp.where(w > 0, A * fmath.power(jnp.maximum(w, 1e-300), gamma),
+                    jnp.inf)
     lam_act = jnp.where(act, ds0 / c_safe, 0.0)
     return P, E, Q, lam_act
 
 
 def _beta_tilde(P, E, Q, act, lam):
     """Uncapped fill curve β̃(λ) = Σ_act max(P λ^E − Q, 0)."""
-    term = P * lam ** E - Q
+    term = P * fmath.power(lam, E) - Q
     return jnp.sum(jnp.where(act, jnp.maximum(term, 0.0), 0.0))
 
 
@@ -497,13 +500,13 @@ def hetero_breakpoints_insert(sp: Speedup, c, k, bp_lam, bp_val, live=True):
 
     c_k = jnp.maximum(c[k], 1e-300)
     E_k, A_k, w_k, s_k = E[k], A[k], w[k], sigma[k]
-    P_k = s_k * (c_k / A_k) ** E_k
+    P_k = s_k * fmath.power(c_k / A_k, E_k)
     Q_k = s_k * w_k
-    ds0_k = jnp.where(w_k > 0, A_k * jnp.maximum(w_k, 1e-300) ** gamma[k],
+    ds0_k = jnp.where(w_k > 0, A_k * fmath.power(jnp.maximum(w_k, 1e-300), gamma[k]),
                       jnp.inf)
     lam_k = ds0_k / c_k
 
-    g = jnp.maximum(P_k * bp_lam ** E_k - Q_k, 0.0)
+    g = jnp.maximum(P_k * fmath.power(bp_lam, E_k) - Q_k, 0.0)
     val_k = _beta_tilde(P, E, Q, prev, lam_k)
     bp_lam2 = jnp.where(idx == k, lam_k, bp_lam)
     bp_val2 = jnp.where(idx == k, val_k, bp_val + g)
@@ -592,13 +595,15 @@ def hetero_solve(prep: HeteroPrep, b, iters: int = 48, lam_hint=None,
 
     # safe bracket — identical bounds to solve_cap_generic
     c_safe = jnp.where(act, c, 1.0)
-    ds_b = prep.A * jnp.maximum(prep.w + prep.sigma * b_safe,
-                                1e-300) ** prep.gamma
+    ds_b = prep.A * fmath.power(
+        jnp.maximum(prep.w + prep.sigma * b_safe, 1e-300), prep.gamma)
     eps = b_safe / (8.0 * M)
     ds0 = jnp.where(prep.w > 0,
-                    prep.A * jnp.maximum(prep.w, 1e-300) ** prep.gamma,
+                    prep.A * fmath.power(jnp.maximum(prep.w, 1e-300),
+                                                     prep.gamma),
                     jnp.inf)
-    ds_top = jnp.where(prep.w > 0, ds0, prep.A * eps ** prep.gamma)
+    ds_top = jnp.where(prep.w > 0, ds0,
+                       prep.A * fmath.power(eps, prep.gamma))
     lam_lo_s = jnp.min(jnp.where(act, ds_b / c_safe, jnp.inf))
     lam_hi_s = jnp.max(jnp.where(act, ds_top / c_safe, -jnp.inf)) * (1 + 1e-9)
     good = (jnp.isfinite(lam_lo_s) & (lam_lo_s > 0) & jnp.isfinite(lam_hi_s)
@@ -640,18 +645,18 @@ def hetero_solve(prep: HeteroPrep, b, iters: int = 48, lam_hint=None,
         flo = _beta_tilde(P, E, Q, act, lo) - b_safe
         fhi = _beta_tilde(P, E, Q, act, hi) - b_safe
 
-    tlo = jnp.log(lo)
-    thi = jnp.log(hi)
+    tlo = fmath.log(lo)
+    thi = fmath.log(hi)
     # init: secant in (t, log β̃) — on any fixed active set β̃ is a sum
     # of pure powers of λ, so log β̃ is near-linear in t = log λ and the
     # log-secant is exact for a one-family segment; fall back to the
     # plain secant (then the log-midpoint) when an end has β̃ = 0
     blo_v = flo + b_safe
     bhi_v = fhi + b_safe
-    lg_b = jnp.log(b_safe)
-    den_l = jnp.log(jnp.maximum(blo_v, 1e-300)) - jnp.log(
+    lg_b = fmath.log(b_safe)
+    den_l = fmath.log(jnp.maximum(blo_v, 1e-300)) - fmath.log(
         jnp.maximum(bhi_v, 1e-300))
-    frac_l = (jnp.log(jnp.maximum(blo_v, 1e-300)) - lg_b) / jnp.where(
+    frac_l = (fmath.log(jnp.maximum(blo_v, 1e-300)) - lg_b) / jnp.where(
         den_l > 0, den_l, 1.0)
     den0 = flo - fhi
     frac = jnp.where((bhi_v > 0) & (den_l > 0), frac_l,
@@ -663,7 +668,7 @@ def hetero_solve(prep: HeteroPrep, b, iters: int = 48, lam_hint=None,
     if lam_hint is not None:
         lam_hint = jnp.asarray(lam_hint, dt)
         use = jnp.isfinite(lam_hint) & (lam_hint > lo) & (lam_hint < hi)
-        t0 = jnp.where(use, jnp.log(jnp.maximum(lam_hint, 1e-300)), t0)
+        t0 = jnp.where(use, fmath.log(jnp.maximum(lam_hint, 1e-300)), t0)
 
     tol = 4.0 * jnp.asarray(jnp.finfo(dt).eps, dt)
     # residual exit: |β̃(λ) − b| ≤ rtol·b means the budget is met to
@@ -680,7 +685,7 @@ def hetero_solve(prep: HeteroPrep, b, iters: int = 48, lam_hint=None,
 
     def body(st):
         i, t, tlo, thi, flo, fhi, side, _ = st
-        u = P * jnp.exp(E * t)
+        u = P * fmath.exp(E * t)
         th = u - Q
         on = act & (th > 0)
         beta = jnp.sum(jnp.where(on, th, 0.0))
@@ -703,7 +708,7 @@ def hetero_solve(prep: HeteroPrep, b, iters: int = 48, lam_hint=None,
         # current active set, so log β̃ is near-linear in t and this
         # step is exact for a one-family segment — plain Newton on β̃
         # stalls in the flat tail where |φ/φ'| overshoots the bracket
-        tn = t - jnp.log(jnp.maximum(beta, 1e-300) / b_safe) * beta / dphi
+        tn = t - fmath.log(jnp.maximum(beta, 1e-300) / b_safe) * beta / dphi
         den = flo2 - fhi2
         tf = tlo2 + (flo2 / jnp.where(den > 0, den, 1.0)) * (thi2 - tlo2)
         use_n = (beta > 0) & jnp.isfinite(tn) & (tn > tlo2) & (tn < thi2)
@@ -728,8 +733,8 @@ def hetero_solve(prep: HeteroPrep, b, iters: int = 48, lam_hint=None,
     else:
         _, t, _, _, _, _, _, _ = jax.lax.while_loop(cond, body, st0)
 
-    lam = jnp.exp(t)
-    theta = jnp.clip(jnp.where(act, P * jnp.exp(E * t) - Q, 0.0),
+    lam = fmath.exp(t)
+    theta = jnp.clip(jnp.where(act, P * fmath.exp(E * t) - Q, 0.0),
                      0.0, b_safe)
     tot = jnp.sum(theta)
     theta = jnp.where(tot > 0, theta * (b_safe / tot), theta)
@@ -782,13 +787,15 @@ def hetero_approx(prep: HeteroPrep, b):
     b_hi_ref = jnp.max(b_safe)
     b_lo_ref = jnp.min(b_safe)
     c_safe = jnp.where(act, c, 1.0)
-    ds_b = prep.A * jnp.maximum(prep.w + prep.sigma * b_hi_ref,
-                                1e-300) ** prep.gamma
+    ds_b = prep.A * fmath.power(
+        jnp.maximum(prep.w + prep.sigma * b_hi_ref, 1e-300), prep.gamma)
     eps = b_lo_ref / (8.0 * M)
     ds0 = jnp.where(prep.w > 0,
-                    prep.A * jnp.maximum(prep.w, 1e-300) ** prep.gamma,
+                    prep.A * fmath.power(jnp.maximum(prep.w, 1e-300),
+                                                     prep.gamma),
                     jnp.inf)
-    ds_top = jnp.where(prep.w > 0, ds0, prep.A * eps ** prep.gamma)
+    ds_top = jnp.where(prep.w > 0, ds0,
+                       prep.A * fmath.power(eps, prep.gamma))
     lam_lo_s = jnp.min(jnp.where(act, ds_b / c_safe, jnp.inf))
     lam_hi_s = jnp.max(jnp.where(act, ds_top / c_safe, -jnp.inf)) * (1 + 1e-9)
     good = (jnp.isfinite(lam_lo_s) & (lam_lo_s > 0) & jnp.isfinite(lam_hi_s)
@@ -806,15 +813,15 @@ def hetero_approx(prep: HeteroPrep, b):
     vhi = prep.vals[idx - 1]      # β̃ at the high-λ end (≤ b)
     ok = (jnp.isfinite(vlo) & jnp.isfinite(vhi) & (vlo > 0) & (vhi > 0)
           & (vlo > vhi))
-    num = jnp.log(jnp.maximum(vlo, 1e-300)) - jnp.log(b_safe)
-    den = jnp.log(jnp.maximum(vlo, 1e-300)) - jnp.log(
+    num = fmath.log(jnp.maximum(vlo, 1e-300)) - fmath.log(b_safe)
+    den = fmath.log(jnp.maximum(vlo, 1e-300)) - fmath.log(
         jnp.maximum(vhi, 1e-300))
     frac = jnp.where(ok, num / jnp.where(den > 0, den, 1.0), 0.5)
-    t = jnp.log(lo) + jnp.clip(frac, 0.0, 1.0) * (jnp.log(hi) - jnp.log(lo))
+    t = fmath.log(lo) + jnp.clip(frac, 0.0, 1.0) * (fmath.log(hi) - fmath.log(lo))
 
     theta = jnp.clip(
         jnp.where(act[None, :],
-                  P[None, :] * jnp.exp(E[None, :] * t[:, None]) - Q[None, :],
+                  P[None, :] * fmath.exp(E[None, :] * t[:, None]) - Q[None, :],
                   0.0),
         0.0, b_safe[:, None])                                  # (G, M)
     tot = jnp.sum(theta, axis=-1, keepdims=True)

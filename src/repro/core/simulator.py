@@ -309,7 +309,7 @@ def _concrete(a):
     """Host view of ``a``, or None if it is a tracer/abstract value."""
     try:
         return np.asarray(a)
-    except Exception:
+    except (TypeError, ValueError):     # tracers, ragged input
         return None
 
 

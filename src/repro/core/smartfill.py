@@ -94,6 +94,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from . import fmath
+
 from .gwf import (hetero_approx, hetero_breakpoints_init,
                   hetero_breakpoints_insert, hetero_prepare, hetero_solve,
                   solve_cap, solve_cap_generic, waterfill_prepare,
@@ -703,8 +705,8 @@ def _solve(sp, x, w, B, m, coarse, descent_iters, cap_iters, fast,
             # zero-weight live job gives μ = 0 exactly, which would put
             # s(0) = 0 on the phase-rate diagonal and NaN the durations.
             mexp = -1.0 / sp.gamma
-            Wk = Wc[k] ** mexp
-            Wk1 = Wc[k - 1] ** mexp
+            Wk = fmath.power(Wc[k], mexp)
+            Wk1 = fmath.power(Wc[k - 1], mexp)
             mu = B * (Wk - Wk1) / jnp.maximum(Wk, 1e-300)
             mu = jnp.clip(mu, _mu_floor(B, dtype), B)
         elif chain is not None:

@@ -61,6 +61,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import fmath
+
 __all__ = [
     "Speedup",
     "RegularSpeedup",
@@ -117,7 +119,7 @@ class Speedup:
 
 def _regular_ds(A, w, gamma, sigma, theta):
     """s'(θ) = A (w + σθ)^γ, elementwise in every parameter."""
-    return A * (w + sigma * theta) ** gamma
+    return A * fmath.power(w + sigma * theta, gamma)
 
 
 def _regular_s(A, w, gamma, sigma, theta):
@@ -133,15 +135,23 @@ def _regular_s(A, w, gamma, sigma, theta):
     base = w + sigma * theta
     g1 = gamma + 1.0
     w_safe = jnp.where(w > 0, w, 1.0)
-    log_branch = (A / sigma) * (jnp.log(base) - jnp.log(w_safe))
+    # log1p/expm1 of the relative step σθ/w: the plain differences
+    # log(w+σθ) − log(w) and (w+σθ)^{γ+1} − w^{γ+1} cancel away every
+    # digit when θ ≪ w — the regime of class aggregates (w → n·w), where
+    # float32 kept no correct digit of s.  w = 0 (pure power) has no
+    # cancellation and keeps the plain power.
+    rel = fmath.log1p(sigma * theta / w_safe)
+    log_branch = (A / sigma) * rel
     safe_g1 = jnp.where(jnp.abs(g1) < 1e-12, 1.0, g1)
-    pow_branch = (A / (sigma * safe_g1)) * (base ** safe_g1 - w ** safe_g1)
+    shifted = fmath.power(w_safe, safe_g1) * fmath.expm1(safe_g1 * rel)
+    diff = jnp.where(w > 0, shifted, fmath.power(base, safe_g1))
+    pow_branch = (A / (sigma * safe_g1)) * diff
     return jnp.where(jnp.abs(g1) < 1e-12, log_branch, pow_branch)
 
 
 def _regular_ds_inv(A, w, gamma, sigma, y):
     """Inverse of ``_regular_ds``: θ = σ((y/A)^{1/γ} − w), elementwise."""
-    return sigma * ((y / A) ** (1.0 / gamma) - w)
+    return sigma * (fmath.power(y / A, 1.0 / gamma) - w)
 
 
 @jax.tree_util.register_pytree_node_class
@@ -187,13 +197,15 @@ class RegularSpeedup(Speedup):
         w = jnp.asarray(self.w, dtype=jnp.result_type(float))
         if self.sigma == +1:
             # γ<0: s'(0) = A·w^γ = +inf when w == 0.
-            return jnp.where(w > 0, self.A * jnp.maximum(w, 1e-300) ** self.gamma, jnp.inf)
-        return self.A * w ** self.gamma
+            return jnp.where(
+                w > 0, self.A * fmath.power(jnp.maximum(w, 1e-300), self.gamma),
+                jnp.inf)
+        return self.A * fmath.power(w, self.gamma)
 
     # -- GWF rectangle-bottle geometry (paper §4.3/4.5.1) --------------
     def bottle_width(self, c):
         """u_i = c_i^{1/γ} (paper: auxiliary g(h)=A(σh)^γ ⇒ θ_i(h)=u_i(h−h_i)+)."""
-        return c ** (1.0 / self.gamma)
+        return fmath.power(c, 1.0 / self.gamma)
 
     def bottle_bottom(self, c):
         """h_i = σ·w / u_i."""
@@ -269,7 +281,8 @@ class StackedSpeedup(Speedup):
         # σ=+1, γ<0, w=0 (pure power): s'(0) = +∞; the σ=−1 saturating
         # family always has w = z ≥ B > 0, so the finite branch covers it.
         return jnp.where(w > 0,
-                         self.A * jnp.maximum(w, 1e-300) ** self.gamma,
+                         self.A * fmath.power(jnp.maximum(w, 1e-300),
+                                              self.gamma),
                          jnp.inf)
 
 

@@ -49,7 +49,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
@@ -86,7 +85,7 @@ def active_fleet_mesh() -> Mesh | None:
     serve/admission.py's simulate estimator): a 1-D mesh context means
     "shard the instance axis here"; a multi-axis (model-parallel) mesh
     is somebody else's and is left alone.  Only *concrete* meshes
-    qualify — on jax ≥ 0.5 ``active_mesh()`` can surface an
+    qualify — ``active_mesh()`` surfaces a ``set_mesh`` mesh as an
     ``AbstractMesh`` (axis names/sizes but no device placement), which
     shard_map cannot be driven with; those fall through to the
     single-device path instead of crashing.
@@ -226,15 +225,13 @@ def _sharded_program(fn, mesh: Mesh):
         _, ys = lax.scan(step, 0, bat_local)
         return ys
 
-    # check_rep=False: the body is collective-free by construction (every
-    # instance is an independent solve), and the replication checker has
-    # no rule for lax.while_loop on this jax line — which the §7
-    # heterogeneous solvers' adaptive exits use (the λ-bisection and the
-    # sorted-bracket Newton polish alike).
-    return jax.jit(shard_map(body, mesh=mesh,
-                             in_specs=(P(None, axis), P()),
-                             out_specs=P(None, axis),
-                             check_rep=False))
+    # check_vma=False: the body is collective-free by construction (every
+    # instance is an independent solve), so there is nothing for the
+    # varying-axes checker to prove.
+    return jax.jit(jax.shard_map(body, mesh=mesh,
+                                 in_specs=(P(None, axis), P()),
+                                 out_specs=P(None, axis),
+                                 check_vma=False))
 
 
 def _run_sharded(mesh: Mesh, fn, batched, shared, N: int,

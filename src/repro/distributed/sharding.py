@@ -86,31 +86,22 @@ def with_logical_rules(overrides: dict[str, tuple[str, ...]]):
 
 
 def active_mesh():
-    """The mesh of the innermost active ``with Mesh(...)`` context, or None.
+    """The mesh in effect, or None.
 
-    Version-tolerant: ``jax.sharding.get_abstract_mesh`` only exists on
-    jax ≥ 0.5 (and ``jax._src.mesh.get_abstract_mesh`` returns a bare
-    axis-name tuple on 0.4.x, so it is no substitute).  The thread-local
-    resource env — what ``pjit``/``shard_map`` themselves consult — is
-    probed first on every version because it holds the *concrete* Mesh
-    (with device placement); the abstract mesh is the fallback and may
-    be an ``AbstractMesh`` with no ``.devices``.  Callers that need
-    device placement must check (see ``fleet.active_fleet_mesh``); axis
-    names/sizes are available on both.
+    A ``with Mesh(...)`` context (the thread-local resource env that
+    ``pjit``/``shard_map`` consult) wins and is returned as the concrete
+    Mesh.  Otherwise the mesh ``set_mesh`` installed is returned as its
+    ``AbstractMesh``: axis names and sizes but no ``.devices``, because
+    only the abstract mesh may be read inside ``jax.jit``, where model
+    code resolves its logical axes.  Callers that need device placement
+    must check (see ``fleet.active_fleet_mesh``).
     """
-    try:
-        from jax._src import mesh as mesh_lib
-        mesh = mesh_lib.thread_resources.env.physical_mesh
-        if mesh is not None and not mesh.empty:
-            return mesh
-    except (ImportError, AttributeError):
-        pass
-    get_abstract = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_abstract is not None:
-        mesh = get_abstract()
-        if mesh is not None and not mesh.empty:
-            return mesh
-    return None
+    from jax._src import mesh as mesh_lib
+    mesh = mesh_lib.thread_resources.env.physical_mesh
+    if not mesh.empty:
+        return mesh
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty else mesh
 
 
 def _mesh_axes():
@@ -121,32 +112,22 @@ def _mesh_axes():
                                   zip(mesh.axis_names, mesh.axis_sizes)}
 
 
-_ENTERED_MESH = None    # 0.4.x set_mesh emulation: the held mesh context
+_INSTALLED = None    # the jax.sharding.set_mesh handle set_mesh holds
 
 
 def set_mesh(mesh):
-    """Install ``mesh`` as the process-wide default (version-tolerant).
+    """Install ``mesh`` as the process-wide default; None clears it.
 
-    jax ≥ 0.6 ships ``jax.sharding.set_mesh``; on 0.4.x we emulate it by
-    holding the thread-local mesh context open (the same state ``with
-    Mesh(...)`` sets and ``active_mesh()``/``pjit`` consult).  Passing
-    None clears an emulated mesh.  Returns the mesh.
+    Wraps ``jax.sharding.set_mesh``, which has no way to clear: the
+    handle of the last install is kept, and clearing restores what it
+    replaced.  Returns the mesh.
     """
-    setter = getattr(jax.sharding, "set_mesh", None)
-    if setter is not None:
-        setter(mesh)
-        return mesh
-    global _ENTERED_MESH
-    if _ENTERED_MESH is not None and active_mesh() is _ENTERED_MESH:
-        # ours is still the innermost context, so popping it is LIFO-safe;
-        # if user code stacked its own `with Mesh(...)` on top, leave ours
-        # in place (exiting out of order would restore a stale env
-        # snapshot and silently corrupt the thread-local mesh stack).
-        _ENTERED_MESH.__exit__(None, None, None)
-    _ENTERED_MESH = None
+    global _INSTALLED
+    if _INSTALLED is not None:
+        _INSTALLED.__exit__(None, None, None)
+        _INSTALLED = None
     if mesh is not None:
-        mesh.__enter__()
-        _ENTERED_MESH = mesh
+        _INSTALLED = jax.sharding.set_mesh(mesh)
     return mesh
 
 

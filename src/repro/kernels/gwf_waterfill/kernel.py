@@ -47,9 +47,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core import fmath
+
 from .ref import lam_bracket
 
 _TILE = 1024  # 8 sublanes × 128 lanes
+_NPAR = 8     # per-instance SMEM parameters of generic_waterfill
 
 
 def _wf_kernel(u_ref, h0_ref, b_ref, theta_ref, *, iters):
@@ -109,15 +112,29 @@ def gwf_waterfill(u, h0, b, *, iters: int = 64, interpret: bool = False):
     return theta.reshape(Mp)[:M]
 
 
+def _bisect_mid(lo, hi):
+    """A probe inside the scalar bracket 0 < lo < hi.
+
+    Geometric while the bracket spans more than a factor of two, then
+    arithmetic: the chip's scalar exp/log are too coarse (about 1e-6
+    relative) to halve a bracket narrower than that, and a scalar has no
+    bitcast in Mosaic, so fmath cannot stand in for them here.
+    """
+    geo = jnp.clip(jnp.exp(0.5 * (jnp.log(lo) + jnp.log(hi))), lo, hi)
+    return jnp.where(hi > 2.0 * lo, geo, 0.5 * (lo + hi))
+
+
 def _generic_wf_kernel(c_ref, par_ref, theta_ref, *, iters, sigma):
     c = c_ref[...]                      # (1, rows, 8, 128) — one instance
-    A = par_ref[0, 0]
-    w = par_ref[0, 1]
-    ginv = par_ref[0, 2]                # 1/γ, precomputed host-side
-    b = par_ref[0, 3]
-    lam_lo = par_ref[0, 4]
-    lam_hi = par_ref[0, 5]
-    ds0 = par_ref[0, 6]
+    # par is the whole flat (N·8,) SMEM table; this step reads its row
+    base = pl.program_id(0) * _NPAR
+    A = par_ref[base]
+    w = par_ref[base + 1]
+    ginv = par_ref[base + 2]            # 1/γ, precomputed host-side
+    b = par_ref[base + 3]
+    lam_lo = par_ref[base + 4]
+    lam_hi = par_ref[base + 5]
+    ds0 = par_ref[base + 6]
     active = c > 0.0
 
     def theta_of(lam):
@@ -125,7 +142,7 @@ def _generic_wf_kernel(c_ref, par_ref, theta_ref, *, iters, sigma):
         # (y/A)^{1/γ} via exp/log — the VPU has no generic power; the
         # base is 1 on inactive lanes so the log stays finite.
         base = jnp.where(active, y / A, 1.0)
-        th = sigma * (jnp.exp(ginv * jnp.log(base)) - w)
+        th = sigma * (fmath.power(base, ginv) - w)
         th = jnp.clip(th, 0.0, b)
         # park jobs whose marginal value at zero is below the pressure
         th = jnp.where(y >= ds0, 0.0, th)
@@ -134,14 +151,14 @@ def _generic_wf_kernel(c_ref, par_ref, theta_ref, *, iters, sigma):
     def body(i, carry):
         lo, hi = carry
         # bisect in log-space for relative precision across wide λ ranges
-        mid = jnp.exp(0.5 * (jnp.log(lo) + jnp.log(hi)))
+        mid = _bisect_mid(lo, hi)
         below = jnp.sum(theta_of(mid)) > b       # β > b ⇒ λ* right of mid
         lo = jnp.where(below, mid, lo)
         hi = jnp.where(below, hi, mid)
         return lo, hi
 
     lo, hi = jax.lax.fori_loop(0, iters, body, (lam_lo, lam_hi))
-    th = theta_of(jnp.exp(0.5 * (jnp.log(lo) + jnp.log(hi))))
+    th = theta_of(_bisect_mid(lo, hi))
     # exact budget: rescale the fp residual onto the positive allocations
     tot = jnp.sum(th)
     th = jnp.where(tot > 0, th * (b / tot), th)
@@ -154,8 +171,10 @@ def generic_waterfill(c, A, w, gamma, b, *, sigma: int = 1, iters: int = 64,
 
     One grid step per instance; each step runs the whole λ-bisection
     over its VMEM-resident block.  A, w, gamma, b are (N,) per-instance
-    scalars (SMEM); ``sigma`` ∈ {+1, −1} is static.  Inactive slots are
-    marked by c = 0.  Kernel math is float32.
+    scalars, passed with the bracket as one flat (N·8,) SMEM table that
+    every step indexes by its instance (a per-step (1, 8) SMEM block is
+    not a legal TPU tiling); ``sigma`` ∈ {+1, −1} is static.  Inactive
+    slots are marked by c = 0.  Kernel math is float32.
     """
     c = jnp.asarray(c)
     if c.ndim != 2:
@@ -174,20 +193,23 @@ def generic_waterfill(c, A, w, gamma, b, *, sigma: int = 1, iters: int = 64,
     cp = cp.reshape(N, rows, 8, 128)
     par = jnp.stack(
         [A, w, 1.0 / gamma, b, lam_lo, lam_hi, ds0, jnp.zeros_like(A)],
-        axis=1).astype(jnp.float32)                      # (N, 8)
+        axis=1).astype(jnp.float32).reshape(N * _NPAR)
 
-    theta = pl.pallas_call(
-        functools.partial(_generic_wf_kernel, iters=iters, sigma=sigma),
-        grid=(N,),
-        in_specs=[
-            pl.BlockSpec((1, rows, 8, 128), lambda n: (n, 0, 0, 0)),
-            pl.BlockSpec((1, 8), lambda n: (n, 0),
-                         memory_space=pltpu.SMEM),
-        ],
-        out_specs=pl.BlockSpec((1, rows, 8, 128), lambda n: (n, 0, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((N, rows, 8, 128), jnp.float32),
-        interpret=interpret,
-    )(cp, par)
+    # traced with x64 off: the kernel is f32 throughout, and under x64
+    # the grid's index maps return int64, which Mosaic refuses
+    with jax.enable_x64(False):
+        theta = pl.pallas_call(
+            functools.partial(_generic_wf_kernel, iters=iters, sigma=sigma),
+            grid=(N,),
+            in_specs=[
+                pl.BlockSpec((1, rows, 8, 128), lambda n: (n, 0, 0, 0)),
+                pl.BlockSpec(memory_space=pltpu.SMEM),
+            ],
+            out_specs=pl.BlockSpec((1, rows, 8, 128),
+                                   lambda n: (n, 0, 0, 0)),
+            out_shape=jax.ShapeDtypeStruct((N, rows, 8, 128), jnp.float32),
+            interpret=interpret,
+        )(cp, par)
     return theta.reshape(N, Kp)[:, :K]
 
 
@@ -201,13 +223,14 @@ def _hetero_wf_kernel(c_ref, A_ref, w_ref, g_ref, s_ref, b_ref, theta_ref,
     w = w_ref[...]
     ginv = 1.0 / g_ref[...]             # γ ≠ 0 for every regular family
     sg = s_ref[...]                     # σ ∈ {±1} per job, as float
-    b = b_ref[0]
+    b = b_ref[pl.program_id(0)]         # whole (N,) budget vector in SMEM
     active = c > 0.0
 
     def po(base, e):
-        # base^e via exp/log — the VPU has no generic power; base is
-        # clamped positive so inactive/edge lanes stay finite.
-        return jnp.exp(e * jnp.log(jnp.maximum(base, 1e-30)))
+        # the VPU has no generic power: fmath's exp/log reductions, which
+        # round alike on every backend; base is clamped positive so
+        # inactive/edge lanes stay finite.
+        return fmath.power(jnp.maximum(base, 1e-30), e)
 
     # per-job bracket & parking threshold (mirrors ref.hetero_lam_bracket).
     # All literals are pinned f32: under jax_enable_x64 a bare python
@@ -240,14 +263,14 @@ def _hetero_wf_kernel(c_ref, A_ref, w_ref, g_ref, s_ref, b_ref, theta_ref,
     def body(i, carry):
         lo, hi = carry
         # bisect in log-space for relative precision across wide λ ranges
-        mid = jnp.exp(0.5 * (jnp.log(lo) + jnp.log(hi)))
+        mid = _bisect_mid(lo, hi)
         below = jnp.sum(theta_of(mid)) > b       # β > b ⇒ λ* right of mid
         lo = jnp.where(below, mid, lo)
         hi = jnp.where(below, hi, mid)
         return lo, hi
 
     lo, hi = jax.lax.fori_loop(0, iters, body, (lam_lo, lam_hi))
-    th = theta_of(jnp.exp(0.5 * (jnp.log(lo) + jnp.log(hi))))
+    th = theta_of(_bisect_mid(lo, hi))
     # exact budget: rescale the fp residual onto the positive allocations
     tot = jnp.sum(th)
     th = jnp.where(tot > 0, th * (b / tot), th)
@@ -289,14 +312,13 @@ def hetero_waterfill(c, A, w, gamma, sigma, b, *, iters: int = 64,
               block(gamma, 1.0), block(sigma, 1.0)]
     spec = pl.BlockSpec((1, rows, 8, 128), lambda n: (n, 0, 0, 0))
 
-    theta = pl.pallas_call(
-        functools.partial(_hetero_wf_kernel, iters=iters),
-        grid=(N,),
-        in_specs=[spec] * 5 + [
-            pl.BlockSpec((1,), lambda n: (n,), memory_space=pltpu.SMEM),
-        ],
-        out_specs=pl.BlockSpec((1, rows, 8, 128), lambda n: (n, 0, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((N, rows, 8, 128), jnp.float32),
-        interpret=interpret,
-    )(*blocks, b.astype(jnp.float32))
+    with jax.enable_x64(False):         # see generic_waterfill
+        theta = pl.pallas_call(
+            functools.partial(_hetero_wf_kernel, iters=iters),
+            grid=(N,),
+            in_specs=[spec] * 5 + [pl.BlockSpec(memory_space=pltpu.SMEM)],
+            out_specs=spec,
+            out_shape=jax.ShapeDtypeStruct((N, rows, 8, 128), jnp.float32),
+            interpret=interpret,
+        )(*blocks, b.astype(jnp.float32))
     return theta.reshape(N, Kp)[:, :K]

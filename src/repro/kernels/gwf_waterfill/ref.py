@@ -25,6 +25,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro.core import fmath
+
 _BIG = 1e30
 
 
@@ -54,11 +56,11 @@ def lam_bracket(c, A, w, gamma, b, sigma):
     c_lo = jnp.min(jnp.where(active, c, jnp.inf), axis=-1)
 
     def ds(t):
-        return A * (w + sigma * t) ** gamma
+        return A * fmath.power(w + sigma * t, gamma)
 
     ds_b = ds(b)
     eps = b / (8.0 * k)
-    ds0 = jnp.where(w > 0, A * jnp.maximum(w, 1e-300) ** gamma,
+    ds0 = jnp.where(w > 0, A * fmath.power(jnp.maximum(w, 1e-300), gamma),
                     jnp.asarray(_BIG, c.dtype))
     ds_top = jnp.where(w > 0, ds0, ds(eps))
     lam_lo = ds_b / c_hi
@@ -85,11 +87,11 @@ def hetero_lam_bracket(c, A, w, gamma, sigma, b):
 
     def ds(t):
         base = jnp.maximum(w + sigma * t, 1e-30)
-        return A * base ** gamma
+        return A * fmath.power(base, gamma)
 
     ds_b = ds(b)
     eps = b / (8.0 * k)
-    ds0 = jnp.where(w > 0, A * jnp.maximum(w, 1e-300) ** gamma,
+    ds0 = jnp.where(w > 0, A * fmath.power(jnp.maximum(w, 1e-300), gamma),
                     jnp.asarray(_BIG, c.dtype))
     ds_top = jnp.where(w > 0, ds0, ds(eps))
     lam_lo = jnp.min(jnp.where(active, ds_b / c, jnp.inf), axis=-1)
@@ -127,19 +129,19 @@ def hetero_waterfill_ref(c, A, w, gamma, sigma, b, iters=64):
         def theta_of(lam):
             y = c1 * lam
             base = jnp.where(active, jnp.maximum(y / A1, 1e-30), 1.0)
-            th = s1 * (base ** (1.0 / g1) - w1)
+            th = s1 * (fmath.power(base, 1.0 / g1) - w1)
             th = jnp.clip(th, 0.0, b1)
             th = jnp.where(y >= ds0, 0.0, th)
             return jnp.where(active, th, 0.0)
 
         def body(_, carry):
             lo, hi = carry
-            mid = jnp.exp(0.5 * (jnp.log(lo) + jnp.log(hi)))
+            mid = fmath.exp(0.5 * (fmath.log(lo) + fmath.log(hi)))
             below = jnp.sum(theta_of(mid)) > b1
             return jnp.where(below, mid, lo), jnp.where(below, hi, mid)
 
         lo, hi = jax.lax.fori_loop(0, iters, body, (lam_lo, lam_hi))
-        th = theta_of(jnp.exp(0.5 * (jnp.log(lo) + jnp.log(hi))))
+        th = theta_of(fmath.exp(0.5 * (fmath.log(lo) + fmath.log(hi))))
         tot = jnp.sum(th)
         th = jnp.where(tot > 0, th * (b1 / tot), th)
         return jnp.minimum(th, b1)
@@ -168,19 +170,19 @@ def generic_waterfill_ref(c, A, w, gamma, b, sigma=1, iters=64):
         def theta_of(lam):
             y = c1 * lam
             base = jnp.where(active, y / A1, 1.0)
-            th = sigma * (base ** (1.0 / g1) - w1)
+            th = sigma * (fmath.power(base, 1.0 / g1) - w1)
             th = jnp.clip(th, 0.0, b1)
             th = jnp.where(y >= ds0, 0.0, th)
             return jnp.where(active, th, 0.0)
 
         def body(_, carry):
             lo, hi = carry
-            mid = jnp.exp(0.5 * (jnp.log(lo) + jnp.log(hi)))
+            mid = fmath.exp(0.5 * (fmath.log(lo) + fmath.log(hi)))
             below = jnp.sum(theta_of(mid)) > b1
             return jnp.where(below, mid, lo), jnp.where(below, hi, mid)
 
         lo, hi = jax.lax.fori_loop(0, iters, body, (lam_lo, lam_hi))
-        th = theta_of(jnp.exp(0.5 * (jnp.log(lo) + jnp.log(hi))))
+        th = theta_of(fmath.exp(0.5 * (fmath.log(lo) + fmath.log(hi))))
         tot = jnp.sum(th)
         th = jnp.where(tot > 0, th * (b1 / tot), th)
         return jnp.minimum(th, b1)

@@ -27,6 +27,7 @@ import dataclasses
 import time
 from typing import Callable
 
+import jax
 import numpy as np
 
 __all__ = ["Watchdog", "WatchdogGiveUp"]
@@ -94,6 +95,8 @@ class Watchdog:
             t0 = self.clock()
             try:
                 out = fn(*args, **kwargs)
+            except jax.errors.JaxRuntimeError:
+                raise       # a device or compile fault: no retry hides it
             except Exception as e:  # noqa: BLE001 — the point is to retry
                 self.failures += 1
                 last_err = e

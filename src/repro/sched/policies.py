@@ -62,6 +62,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import fmath
+
 from repro.core.gwf import (solve_cap, solve_cap_hetero,
                             solve_cap_hetero_sorted)
 from repro.core.smartfill import (WarmStart, _fast_ok, _is_pure_power,
@@ -182,7 +184,7 @@ class HeSRPTPolicy(Policy):
         m = jnp.sum(active)
         mexp = 1.0 / (1.0 - self.p)
         Wc = jnp.cumsum(ws)
-        Wm = jnp.maximum(Wc, 0.0) ** mexp
+        Wm = fmath.power(jnp.maximum(Wc, 0.0), mexp)
         Wm_prev = jnp.concatenate([jnp.zeros((1,), Wm.dtype), Wm[:-1]])
         Wk = Wm[jnp.maximum(m - 1, 0)]
         shares = self._budget(B) * (Wm - Wm_prev) / jnp.maximum(Wk, _TINY)

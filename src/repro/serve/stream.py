@@ -296,6 +296,8 @@ class StreamController:
         try:
             plan = self.policy.plan(rem, w, active, B=B_live)
             failed = not plan.certified
+        except jax.errors.JaxRuntimeError:
+            raise           # a device or compile fault is not a bad plan
         except (FloatingPointError, ValueError, RuntimeError):
             plan, failed = None, True
         if not failed:

@@ -84,3 +84,22 @@ def test_from_roofline_is_regular_and_concave():
     s1 = float(sp.s(jnp.float64(8.0)))
     s2 = float(sp.s(jnp.float64(16.0)))
     assert s1 < s2 < 2 * s1
+
+
+@pytest.mark.parametrize("name", ["shifted", "log", "neg_power", "saturating"])
+def test_s_keeps_f32_digits_when_theta_much_smaller_than_w(name):
+    """Class aggregates (core/classes.py) scale the shift w by the class
+    count n while each job's share θ stays small: s must not lose its
+    float32 digits to the cancellation in (w+σθ)^{γ+1} − w^{γ+1}."""
+    import jax
+
+    from repro.core import class_speedup
+
+    n = 31_250.0
+    th = np.linspace(1e-3, 1.0, 64) * (0.9 if name == "saturating" else B)
+    ref = np.asarray(class_speedup(FAMILIES[name], n).s(jnp.asarray(th)),
+                     np.float64)
+    with jax.enable_x64(False):
+        sp32 = class_speedup(FAMILIES[name], jnp.float32(n))
+        got = np.asarray(sp32.s(jnp.asarray(th, jnp.float32)), np.float64)
+    np.testing.assert_allclose(got, ref, rtol=1e-5)

@@ -78,7 +78,7 @@ def test_plan_parity_f64(family):
 @pytest.mark.parametrize("family", sorted(_SPS))
 def test_plan_parity_f32(family):
     X, W, _ = _workloads(1)
-    with jax.experimental.disable_x64():
+    with jax.enable_x64(False):
         _run_plan_parity(_SPS[family](), X, W, jnp.float32)
 
 
@@ -153,7 +153,7 @@ def test_ensemble_parity(dtype):
         _assert_ensemble_parity(ref, sh)
 
     if dtype == "f32":
-        with jax.experimental.disable_x64():
+        with jax.enable_x64(False):
             run()
     else:
         run()

@@ -1,11 +1,8 @@
-"""Regression tests for the version-tolerant active-mesh shim.
+"""Tests for the active-mesh helpers of ``distributed/sharding.py``.
 
-``jax.sharding.get_abstract_mesh`` does not exist on jax 0.4.x — the
-old direct call made *every* model/train smoke test die with
-AttributeError before any assertion ran.  These tests pin the shim's
-contract directly: ``logical_to_spec``/``constrain`` resolve against
-the innermost ``with Mesh(...)`` context and are exact no-ops without
-one, on every supported jax version.
+``logical_to_spec``/``constrain`` resolve against the innermost
+``with Mesh(...)`` context or the mesh ``set_mesh`` installed, and are
+exact no-ops without one.
 """
 import jax
 import jax.numpy as jnp
@@ -39,9 +36,8 @@ def test_active_mesh_tracks_context():
 
 
 def test_set_mesh_installs_and_clears():
-    """jax.sharding.set_mesh does not exist on 0.4.x either — the shim
-    must install a process-wide mesh that logical_to_spec resolves
-    against, and clear it again on set_mesh(None)."""
+    """set_mesh installs a process-wide mesh that logical_to_spec
+    resolves against, and set_mesh(None) clears it again."""
     mesh = _mesh_2d()
     try:
         set_mesh(mesh)

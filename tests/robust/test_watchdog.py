@@ -55,6 +55,21 @@ def test_gives_up_with_cause():
     assert wd.giveups == 1 and wd.attempts == 2
 
 
+def test_device_fault_propagates_without_retry():
+    """A JaxRuntimeError is a device or compile fault, not a flaky call:
+    retrying would hide the lost device behind a degraded decision."""
+    import jax
+
+    wd, vc = _wd(retries=3)
+
+    def lost():
+        raise jax.errors.JaxRuntimeError("INTERNAL: device fault")
+
+    with pytest.raises(jax.errors.JaxRuntimeError):
+        wd.call(lost)
+    assert wd.attempts == 1 and vc.sleeps == []
+
+
 def test_validation_rejects_bad_results():
     wd, _ = _wd(retries=2, backoff_s=0.0)
     results = iter([np.array([np.nan]), np.array([np.inf]),

@@ -10,6 +10,7 @@ whole-stream metrics agree to reference precision.
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from repro.core import (
@@ -349,6 +350,28 @@ def test_stream_uncertified_replan_falls_to_ladder():
     assert res.degraded_windows == res.replans > 0
     # the ladder's SmartFill rung is healthy, so jobs still finish
     assert res.metrics.n_completed == res.metrics.n_admitted
+
+
+@pytest.mark.parametrize("exc, absorbed", [
+    (ValueError("infeasible order"), True),
+    (jax.errors.JaxRuntimeError("INTERNAL: device fault"), False),
+])
+def test_stream_replan_fault_routing(exc, absorbed):
+    """Solver errors fall to the ladder; a device or compile fault
+    (``JaxRuntimeError``) propagates instead of passing for a degraded
+    window."""
+    class Faulty(StreamingSmartFillPolicy):
+        def plan(self, rem, w, active=None, B=None, warm=True):
+            raise exc
+
+    stream = sample_arrival_stream(5, horizon=4000.0, rate=0.01, B=B)
+    ctl = StreamController(SP, B, max_live=4, policy=Faulty(SP, B))
+    if absorbed:
+        res = ctl.run(stream)
+        assert res.degraded_windows == res.replans > 0
+    else:
+        with pytest.raises(jax.errors.JaxRuntimeError, match="device"):
+            ctl.run(stream)
 
 
 def test_stream_rejects_per_job_speedup():
