@@ -93,12 +93,12 @@ def _stream_row(name, res, best, horizon):
         "horizon_s": horizon,
         "arrivals": m.n_arrivals,
         "completed": m.n_completed,
-        "events": res.n_events,
+        "events": res.n_windows,
         "replans": res.replans,
         "warm_replans": res.warm_replans,
         "cold_replans": res.cold_replans,
         "degraded_windows": res.degraded_windows,
-        "events_per_sec": res.n_events / best,
+        "events_per_sec": res.n_windows / best,
         "arrivals_per_sec": m.n_arrivals / best,
         "weighted_J": m.weighted_J,
         "mean_slowdown": m.mean_slowdown,
@@ -188,7 +188,7 @@ def bench_multitenant_at(tenants: int, quick: bool) -> list:
         t0 = time.perf_counter()
         fleet = run()
         best = min(best, time.perf_counter() - t0)
-    events = sum(r.n_events for r in fleet.results)
+    events = sum(r.n_windows for r in fleet.results)
     q = "_quick" if quick else "_day"
     return [{
         "name": f"serve_multitenant{q}_T{tenants}",

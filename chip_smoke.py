@@ -165,7 +165,7 @@ def _nonfinite(res):
     m = res.metrics
     vals = [m.weighted_J, m.mean_flow, m.mean_slowdown, m.p50_latency,
             m.p99_latency, res.replans, res.warm_replans, res.cold_replans,
-            res.degraded_windows, res.n_events]
+            res.degraded_windows, res.n_windows]
     return int(np.sum(~np.isfinite(np.asarray(vals, np.float64))))
 
 
@@ -177,14 +177,14 @@ def phase_stream(chip, cpu):
     with jax.default_device(chip):
         ctl = _stream_controller(16)
         dev, first, warm = _timed(lambda: ctl.run_device(stream))
-    _setup_line("stream", f"run_device_E{dev.n_events}", first, warm)
+    _setup_line("stream", f"run_device_W{dev.n_windows}", first, warm)
     t0 = time.perf_counter()
     with _reference(cpu):
         ref = _stream_controller(16).run(stream)
     print(f"setup stream.cpu_f64_host_oracle_s={time.perf_counter() - t0!r}",
           flush=True)
     dm, rm = dev.metrics, ref.metrics
-    print(f"info stream: arrivals={n} events={dev.n_events} "
+    print(f"info stream: arrivals={n} windows={dev.n_windows} "
           f"replans={dev.replans} warm={dev.warm_replans} "
           f"cold={dev.cold_replans} degraded={dev.degraded_windows} "
           f"(cpu f64: replans={ref.replans} warm={ref.warm_replans} "

@@ -52,6 +52,7 @@ from jax import lax
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
+from repro import obs
 from repro.core.batch import (BatchedSmartFillSchedule, _prepare,
                               check_axes_unambiguous, hetero_order_batch,
                               validate_padded_instances)
@@ -661,16 +662,45 @@ def serve_streams_sharded(
     Returns a ``FleetStreamResult``: per-tenant ``StreamResult``s plus
     the cross-tenant admission view (backlog, unfinished work, SLO
     columns, and the advisory ``suggested_budget_share``).
-    """
-    from repro.robust.degrade import DegradingPolicy
-    from repro.serve.stream import (StreamController, _event_arrays,
-                                    _stream_state0)
 
-    streams = tuple(streams)
+    Spans as ``StreamController.run_device``'s: one ``repro.scan`` with
+    ``.prepare``, ``.wait`` and ``.finish``; the tenants' work counters
+    summed into ``repro.scan.<name>``.
+    """
+    from repro.serve.stream import _read_scan
+
+    with obs.span("repro.scan"):
+        with obs.span("repro.scan.prepare"):
+            streams = tuple(streams)
+            sp = collapse_homogeneous(sp)
+            if budgets is None:
+                budgets = [float(sp.B)] * len(streams)
+            budgets = [float(b) for b in budgets]
+            out = _dispatch_streams(
+                sp, streams, budgets, max_live, mesh, chunk_size,
+                plan_latency, rtol, certificate_rtol, coarse,
+                descent_iters, cap_iters, stol_rel, search_steps)
+        with obs.span("repro.scan.wait"):
+            out = jax.block_until_ready(out)
+        with obs.span("repro.scan.finish"):
+            counters, got = _read_scan(
+                out, ("rem", "active", "qbuf", "qhead", "qtail"))
+            return _fleet_result(sp, streams, budgets, max_live,
+                                 plan_latency, rtol, counters, got)
+
+
+def _dispatch_streams(sp, streams, budgets, max_live, mesh, chunk_size,
+                      plan_latency, rtol, certificate_rtol, coarse,
+                      descent_iters, cap_iters, stol_rel, search_steps):
+    """``serve_streams_sharded``'s host side up to the dispatch: checks,
+    event arrays, initial states, ladders and padding; returns the
+    tenants' final scan states (not waited for)."""
+    from repro.robust.degrade import DegradingPolicy
+    from repro.serve.stream import _event_arrays, _stream_state0
+
     T = len(streams)
     if T < 1:
         raise ValueError("need at least one tenant stream")
-    sp = collapse_homogeneous(sp)
     if any(getattr(l, "ndim", 0) >= 1
            for l in jax.tree_util.tree_leaves(sp)):
         raise ValueError(
@@ -680,9 +710,6 @@ def serve_streams_sharded(
     if M < 1:
         raise ValueError("max_live must be >= 1")
     dtype = jnp.result_type(float)
-    if budgets is None:
-        budgets = [float(sp.B)] * T
-    budgets = [float(b) for b in budgets]
     if len(budgets) != T:
         raise ValueError("budgets must give one nominal budget per tenant")
 
@@ -708,9 +735,9 @@ def serve_streams_sharded(
     state = jax.tree_util.tree_map(
         lambda *ls: jnp.stack(ls),
         *[_stream_state0(M, Nmax, budgets[i], dtype) for i in range(T)])
+    ladders = [DegradingPolicy.ladder(sp, B=b) for b in budgets]
     lad_st = jax.tree_util.tree_map(
-        lambda *ls: jnp.stack([jnp.asarray(l) for l in ls]),
-        *[DegradingPolicy.ladder(sp, B=b) for b in budgets])
+        lambda *ls: jnp.stack([jnp.asarray(l) for l in ls]), *ladders)
     lad_split = _SplitLeaves(lad_st, T)
 
     mesh = _resolve_mesh(mesh)
@@ -731,27 +758,34 @@ def serve_streams_sharded(
     fn = _serve_fn(lad_split.key, _fast_ok(sp), int(coarse),
                    int(descent_iters), int(cap_iters), stol_rel,
                    4 * M if search_steps is None else int(search_steps))
-    out = _run_sharded(mesh, fn, batched, shared, T, chunk_size)
+    # four event arrays, x, w, budgets, three shared scalars, and the
+    # host leaves of the speedup and the ladders
+    obs.count("repro.h2d", 10 + obs.host_leaves(sp, ladders))
+    return _run_sharded(mesh, fn, batched, shared, T, chunk_size)
 
-    comp_all = np.asarray(out["completion"], float)
-    rem = np.asarray(out["rem"], float)
-    act = np.asarray(out["active"], bool)
-    qb = np.asarray(out["qbuf"])
-    qh = np.asarray(out["qhead"])
-    qt = np.asarray(out["qtail"])
+
+def _fleet_result(sp, streams, budgets, max_live, plan_latency, rtol,
+                  counters, got) -> FleetStreamResult:
+    """Per-tenant results and the admission view from the one read of
+    the tenants' final states."""
+    from repro.serve.stream import StreamController
+
+    T = len(streams)
+    M = int(max_live)
+    comp_all = np.asarray(got["completion"], float)
+    rem = np.asarray(got["rem"], float)
+    act = np.asarray(got["active"], bool)
+    qb, qh, qt = got["qbuf"], got["qhead"], got["qtail"]
     results = []
     backlog = np.zeros(T, int)
     work = np.zeros(T)
     for i, strm in enumerate(streams):
+        n = len(strm)
         ctl = StreamController(sp, budgets[i], max_live=M,
                                plan_latency=plan_latency, rtol=rtol)
         results.append(ctl._finalize(
-            strm, comp_all[i, :Ns[i]], np.ones(Ns[i], bool),
-            replans=int(out["replans"][i]),
-            warm_replans=int(out["warm_ct"][i]),
-            cold_replans=int(out["cold_ct"][i]),
-            degraded=int(out["degraded"][i]),
-            n_windows=int(out["n_windows"][i])))
+            strm, comp_all[i, :n], np.ones(n, bool),
+            **{k: v[i] for k, v in counters.items()}))
         qidx = qb[i, qh[i]:qt[i]]
         backlog[i] = int(act[i].sum()) + qidx.size
         work[i] = float(np.sum(rem[i] * act[i]))

@@ -62,6 +62,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import fmath
 
 from repro.core.gwf import (solve_cap, solve_cap_hetero,
@@ -768,7 +769,8 @@ def _exchange_search_shared(run_order, order0, out0, m, max_steps):
 
     Starts from a failed fresh order, scores all M−1 adjacent swaps
     with one vmapped solve per step, and takes the best strictly-
-    improving swap until none improves (or ``max_steps``).  The
+    improving swap until none improves (or ``max_steps``).  Returns the
+    order, its solve and the number of sweeps run (M−1 solves each).  The
     shared-speedup analogue of the §7 host search the streaming policy
     escalates to — on the day trace the fresh SJF ranking certifies
     ~98% of replans and this search rescues nearly all of the rest
@@ -806,7 +808,7 @@ def _exchange_search_shared(run_order, order0, out0, m, max_steps):
         keep_going, sweep,
         (order0, out0, bestJ0, jnp.zeros((), jnp.int32),
          jnp.ones((), bool)))
-    return st[0], st[1]
+    return st[0], st[1], st[3]
 
 
 def stream_replan_core(sp, ladder, rem, w, active, B_live, B_key, warm,
@@ -830,13 +832,18 @@ def stream_replan_core(sp, ladder, rem, w, active, B_live, B_key, warm,
          solver failures are absorbed, never executed).
 
     Returns ``(order, table, m, certified, searched, J, J_linear,
-    warm2)`` with ``order`` a full (M,) slot permutation (live prefix
-    first), ``table`` the (M, M) plan to execute, and ``warm2`` the
-    carry for the next event.  ``StreamCascadePolicy`` (host) and
+    warm2, sweeps)`` with ``order`` a full (M,) slot permutation (live
+    prefix first), ``table`` the (M, M) plan to execute, ``warm2`` the
+    carry for the next event and ``sweeps`` the exchange search's sweeps
+    (0 when it did not run; M−1 solves each).  Each stage runs under a
+    ``jax.named_scope`` (``fresh_solve``, ``exchange_search``,
+    ``ladder``) for readers of the device trace.
+    ``StreamCascadePolicy`` (host) and
     ``serve.stream.StreamController.run_device`` call this *same*
     function, which is what makes the host loop a bit-comparable
     differential oracle for the device scan.
     """
+    obs.count("repro.retrace.stream_replan_core")
     rem = jnp.asarray(rem)
     dtype = rem.dtype
     M = rem.shape[0]
@@ -856,32 +863,38 @@ def stream_replan_core(sp, ladder, rem, w, active, B_live, B_key, warm,
                       cap_iters, fast, lam0=warm.lam, stol_rel=stol_rel,
                       bracket0=warm.bracket)
 
-    out0 = run_order(order0)
-    cert0 = _stream_certified(out0[5], out0[6], certificate_rtol, dtype)
+    with jax.named_scope("fresh_solve"):
+        out0 = run_order(order0)
+        cert0 = _stream_certified(out0[5], out0[6], certificate_rtol,
+                                  dtype)
     need_search = (~cert0) & (m > 1)
 
     def escalate(_):
-        return _exchange_search_shared(run_order, order0, out0, m,
-                                       search_steps)
+        with jax.named_scope("exchange_search"):
+            return _exchange_search_shared(run_order, order0, out0, m,
+                                           search_steps)
 
-    order1, out1 = jax.lax.cond(need_search, escalate,
-                                lambda _: (order0, out0), None)
+    order1, out1, sweeps = jax.lax.cond(
+        need_search, escalate,
+        lambda _: (order0, out0, jnp.zeros((), jnp.int32)), None)
     certified = _stream_certified(out1[5], out1[6], certificate_rtol,
                                   dtype)
 
     def ladder_plan(_):
         from repro.robust.degrade import ladder_plan_table
-        order_l = jnp.argsort(jnp.where(act, -rem, jnp.inf),
-                              stable=True).astype(jnp.int32)
-        rem_l = jnp.where(idx < m, rem[order_l], 0.0)
-        w_l = jnp.where(idx < m, w[order_l], 0.0)
-        return order_l, ladder_plan_table(ladder, rem_l, w_l, B=B_live)
+        with jax.named_scope("ladder"):
+            order_l = jnp.argsort(jnp.where(act, -rem, jnp.inf),
+                                  stable=True).astype(jnp.int32)
+            rem_l = jnp.where(idx < m, rem[order_l], 0.0)
+            w_l = jnp.where(idx < m, w[order_l], 0.0)
+            return order_l, ladder_plan_table(ladder, rem_l, w_l,
+                                              B=B_live)
 
     order_f, table_f = jax.lax.cond(
         certified, lambda _: (order1, out1[0]), ladder_plan, None)
     warm2 = WarmStart(lam=out1[7], bracket=out1[8])
     return (order_f, table_f, m.astype(jnp.int32), certified,
-            need_search, out1[5], out1[6], warm2)
+            need_search, out1[5], out1[6], warm2, sweeps)
 
 
 def stream_warm0(M: int, dtype=None) -> WarmStart:
@@ -920,7 +933,12 @@ class StreamCascadePolicy:
     Counter semantics (device-mirrored, coarser than the streaming
     policy's): ``warm_replans`` counts replans certified on the fresh
     hinted solve, ``cold_replans`` counts escalations (search or
-    ladder), ``order_searches`` counts search entries.
+    ladder), ``order_searches`` counts search entries and
+    ``search_solves`` the exchange search's solves (sweeps × (M−1)).
+
+    Each ``plan`` is the span ``repro.plan``: ``repro.plan.dispatch``
+    (uploads and the cascade call) and ``repro.plan.readback`` (the
+    reads the decision needs), counted in ``repro.h2d``/``repro.d2h``.
     """
 
     device_ready = False
@@ -949,6 +967,9 @@ class StreamCascadePolicy:
             from repro.robust.degrade import DegradingPolicy
             ladder = DegradingPolicy.ladder(self.sp, B=self.B)
         self.ladder = ladder
+        # rem, w, active and three scalars, with the host leaves of the
+        # speedup and ladder: what each cascade call uploads
+        self._h2d = 6 + obs.host_leaves(self.sp, self.ladder)
         self.reset()
 
     def reset(self) -> None:
@@ -956,41 +977,52 @@ class StreamCascadePolicy:
         self.warm_replans = 0
         self.cold_replans = 0
         self.order_searches = 0
+        self.search_solves = 0
 
     def release(self, slots) -> None:
         """No carried order — nothing to forget on slot recycling."""
 
     def plan(self, rem, w, active=None, B=None) -> StreamPlan:
-        rem = np.asarray(rem, float)
-        w = np.asarray(w, float)
-        M = rem.shape[0]
-        act = (np.ones(M, bool) if active is None
-               else np.asarray(active, bool))
-        Bv = float(self.B if B is None else B)
-        dtype = jnp.result_type(float)
-        if self.warm is None or self.warm.lam.shape != (M,):
-            self.warm = stream_warm0(M, dtype)
-        steps = (4 * M if self.search_steps is None
-                 else int(self.search_steps))
-        order, table, m_, certified, searched, J, J_lin, warm2 = (
-            _cascade_call(self.sp, self.ladder, jnp.asarray(rem, dtype),
-                          jnp.asarray(w, dtype), jnp.asarray(act),
-                          Bv, self.B, self.warm, self.certificate_rtol,
-                          fast=self._fast, coarse=self.coarse,
-                          descent_iters=self.descent_iters,
-                          cap_iters=self.cap_iters,
-                          stol_rel=self.stol_rel, search_steps=steps))
-        self.warm = WarmStart(lam=warm2.lam, bracket=warm2.bracket)
-        m = int(m_)
-        cert = bool(certified)
-        sd = bool(searched)
-        self.warm_replans += int(cert and not sd)
-        self.cold_replans += int(sd or not cert)
-        self.order_searches += int(sd)
-        return StreamPlan(order=np.asarray(order, np.int64)[:m],
-                          table=table, J=float(J), J_linear=float(J_lin),
-                          m=m, B=Bv, warm=cert and not sd,
-                          certified=cert)
+        with obs.span("repro.plan"):
+            rem = np.asarray(rem, float)
+            w = np.asarray(w, float)
+            M = rem.shape[0]
+            act = (np.ones(M, bool) if active is None
+                   else np.asarray(active, bool))
+            Bv = float(self.B if B is None else B)
+            dtype = jnp.result_type(float)
+            if self.warm is None or self.warm.lam.shape != (M,):
+                self.warm = stream_warm0(M, dtype)
+            steps = (4 * M if self.search_steps is None
+                     else int(self.search_steps))
+            with obs.span("repro.plan.dispatch"):
+                obs.count("repro.h2d", self._h2d)
+                (order, table, m_, certified, searched, J, J_lin, warm2,
+                 sweeps) = _cascade_call(
+                    self.sp, self.ladder, jnp.asarray(rem, dtype),
+                    jnp.asarray(w, dtype), jnp.asarray(act), Bv, self.B,
+                    self.warm, self.certificate_rtol, fast=self._fast,
+                    coarse=self.coarse, descent_iters=self.descent_iters,
+                    cap_iters=self.cap_iters, stol_rel=self.stol_rel,
+                    search_steps=steps)
+            self.warm = WarmStart(lam=warm2.lam, bracket=warm2.bracket)
+            with obs.span("repro.plan.readback"):
+                m = int(m_)
+                cert = bool(certified)
+                sd = bool(searched)
+                order = np.asarray(order, np.int64)
+                J = float(J)
+                J_lin = float(J_lin)
+                # the sweep count is read only when the search ran
+                solves = int(sweeps) * (M - 1) if sd else 0
+                obs.count("repro.d2h", 6 + sd)
+            self.warm_replans += int(cert and not sd)
+            self.cold_replans += int(sd or not cert)
+            self.order_searches += int(sd)
+            self.search_solves += solves
+            return StreamPlan(order=order[:m], table=table, J=J,
+                              J_linear=J_lin, m=m, B=Bv,
+                              warm=cert and not sd, certified=cert)
 
     def __call__(self, rem, w, active, B=None):
         """Host-policy adapter: the current-phase allocation column."""

@@ -60,6 +60,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.smartfill import _fast_ok
 from repro.core.speedup import Speedup, collapse_homogeneous, is_per_job
 from repro.core.workloads import ArrivalStream
@@ -113,36 +114,74 @@ def _exec_window(sp, table, rem0, live0, span, rtol):
     Returns ``(rem_end, live_end, comp)`` with ``comp[i]`` the
     completion offset from the window start (+inf where row i survived).
     """
-    M = rem0.shape[0]
-    dtype = rem0.dtype
-    idx = jnp.arange(M)
-    tol = (jnp.maximum(jnp.asarray(rtol, dtype),
-                       8.0 * jnp.finfo(dtype).eps)
-           * jnp.maximum(1.0, jnp.max(rem0)))
-    inf = jnp.asarray(jnp.inf, dtype)
+    obs.count("repro.retrace._exec_window")
+    with jax.named_scope("exec_window"):
+        M = rem0.shape[0]
+        dtype = rem0.dtype
+        idx = jnp.arange(M)
+        tol = (jnp.maximum(jnp.asarray(rtol, dtype),
+                           8.0 * jnp.finfo(dtype).eps)
+               * jnp.maximum(1.0, jnp.max(rem0)))
+        inf = jnp.asarray(jnp.inf, dtype)
 
-    def step(carry, _):
-        rem, live, left, elapsed, comp = carry
-        m = jnp.sum(live)
-        colm = jnp.take(table, jnp.clip(m - 1, 0, M - 1), axis=1)
-        rank = jnp.clip(jnp.cumsum(live) - 1, 0, M - 1)
-        th = jnp.where(live, jnp.take(colm, rank), 0.0)
-        rate = jnp.where(live, sp.s(th), 0.0)
-        dt = jnp.where(live & (rate > 0),
-                       rem / jnp.maximum(rate, _rate_floor(dtype)), inf)
-        h = jnp.minimum(jnp.min(dt), left)
-        h = jnp.maximum(h, 0.0)
-        rem2 = jnp.where(live, jnp.maximum(rem - rate * h, 0.0), rem)
-        done = live & (rem2 <= tol)
-        comp = jnp.where(done, elapsed + h, comp)
-        return (jnp.where(done, 0.0, rem2), live & ~done, left - h,
-                elapsed + h, comp), None
+        def step(carry, _):
+            rem, live, left, elapsed, comp = carry
+            m = jnp.sum(live)
+            colm = jnp.take(table, jnp.clip(m - 1, 0, M - 1), axis=1)
+            rank = jnp.clip(jnp.cumsum(live) - 1, 0, M - 1)
+            th = jnp.where(live, jnp.take(colm, rank), 0.0)
+            rate = jnp.where(live, sp.s(th), 0.0)
+            dt = jnp.where(live & (rate > 0),
+                           rem / jnp.maximum(rate, _rate_floor(dtype)), inf)
+            h = jnp.minimum(jnp.min(dt), left)
+            h = jnp.maximum(h, 0.0)
+            rem2 = jnp.where(live, jnp.maximum(rem - rate * h, 0.0), rem)
+            done = live & (rem2 <= tol)
+            comp = jnp.where(done, elapsed + h, comp)
+            return (jnp.where(done, 0.0, rem2), live & ~done, left - h,
+                    elapsed + h, comp), None
 
-    carry0 = (rem0, live0, jnp.asarray(span, dtype),
-              jnp.zeros((), dtype), jnp.full((M,), jnp.inf, dtype))
-    (rem, live, _, _, comp), _ = jax.lax.scan(
-        step, carry0, None, length=M + 1)
-    return rem, live, comp
+        carry0 = (rem0, live0, jnp.asarray(span, dtype),
+                  jnp.zeros((), dtype), jnp.full((M,), jnp.inf, dtype))
+        (rem, live, _, _, comp), _ = jax.lax.scan(
+            step, carry0, None, length=M + 1)
+        return rem, live, comp
+
+
+def _plan_excess(table, m, B_live):
+    """How far the plan's current column (m−1), summed over its m live
+    rows, exceeds the live budget; traced."""
+    M = table.shape[0]
+    col = jnp.take(table, jnp.clip(m - 1, 0, M - 1), axis=1)
+    alloc = jnp.sum(jnp.where(jnp.arange(M) < m, col, 0.0))
+    return jnp.where(m > 0, alloc - B_live, 0.0)
+
+
+# plans the host loop checks in one device call (a fixed count, so the
+# call compiles once)
+_EXCESS_BATCH = 256
+
+
+@jax.jit
+def _excess_batch(tables, m, B_live):
+    """The most by which one of the plans exceeds its budget."""
+    return jnp.max(jax.vmap(_plan_excess)(jnp.stack(tables), m, B_live))
+
+
+def _budget_excess(plans, excess: float = 0.0) -> float:
+    """``excess`` raised to the most by which one of ``plans`` (at most
+    ``_EXCESS_BATCH``) exceeds its budget: one call, one read."""
+    if not plans:
+        return excess
+    n = len(plans)
+    tables = [p.table for p in plans] + [plans[0].table] * (_EXCESS_BATCH - n)
+    m = np.zeros(_EXCESS_BATCH, np.int32)      # m = 0 pads read as 0
+    B = np.zeros(_EXCESS_BATCH)
+    m[:n] = [p.m for p in plans]
+    B[:n] = [p.B for p in plans]
+    obs.count("repro.h2d", 2 + obs.host_leaves(tables))
+    obs.count("repro.d2h")
+    return max(excess, float(_excess_batch(tuple(tables), m, B)))
 
 
 # ---------------------------------------------------------------------------
@@ -175,8 +214,13 @@ class StreamResult:
     ``completion`` is +inf for jobs still live (or rejected) at the
     horizon.  ``replans``/``warm_replans``/``cold_replans`` count
     planner invocations; ``degraded_windows`` counts windows executed
-    on the ladder fallback table; ``n_events`` counts control-plane
-    events (windows), not engine steps.
+    on the ladder fallback table; ``n_windows`` counts executed windows,
+    not engine steps.  The work counters: ``search_solves`` the solves
+    of the cascade's exchange search, sweeps × (M−1) (0 under a policy
+    that does not count them); ``cut_reruns`` the windows run a second
+    time to stop at a first completion; ``budget_excess`` the most by
+    which a published plan's current allocation column, summed over the
+    live jobs, exceeded the live budget B(t) (0 when none did).
     """
 
     metrics: StreamMetrics
@@ -188,7 +232,10 @@ class StreamResult:
     warm_replans: int
     cold_replans: int
     degraded_windows: int
-    n_events: int
+    n_windows: int
+    search_solves: int
+    cut_reruns: int
+    budget_excess: float
 
 
 # ---------------------------------------------------------------------------
@@ -278,6 +325,9 @@ class StreamController:
                        if ladder is None else ladder)
         self.plan_latency = float(plan_latency)
         self.rtol = float(rtol)
+        # rem, live, span and rtol, with the speedup's host leaves: what
+        # each window call uploads besides a host-built table
+        self._window_h2d = 4 + obs.host_leaves(self.sp)
 
     # -- internals --------------------------------------------------------
 
@@ -290,9 +340,10 @@ class StreamController:
         # watchdog exhaustion fails closed (deny-all, status degraded)
         return bool(dec.admit[0])
 
-    def _replan(self, t, rem, w, active, B_live, buffer) -> tuple[int, int]:
+    def _replan(self, t, rem, w, active, B_live, buffer) -> StreamPlan:
         """Solve on the live state; publish certified plans, fall down
-        the ladder otherwise.  Returns (degraded, replanned) counts."""
+        the ladder otherwise.  Returns the plan published (uncertified
+        when it came from the ladder)."""
         try:
             plan = self.policy.plan(rem, w, active, B=B_live)
             failed = not plan.certified
@@ -302,7 +353,7 @@ class StreamController:
             plan, failed = None, True
         if not failed:
             buffer.publish(plan, ready_at=t + self.plan_latency)
-            return 0, 1
+            return plan
         # ladder fallback: certificate-gated columns on the *current*
         # SJF ranking — published instantly (the emergency plan must
         # not sit behind a solve latency)
@@ -313,20 +364,23 @@ class StreamController:
         rem_rows[:m] = rem[order]
         w_rows[:m] = w[order]
         table = ladder_plan_table(self.ladder, rem_rows, w_rows, B=B_live)
-        buffer.publish(StreamPlan(
+        plan = StreamPlan(
             order=order, table=table, J=float("nan"), J_linear=float("nan"),
-            m=m, B=B_live, warm=False, certified=False))
-        return 1, 1
+            m=m, B=B_live, warm=False, certified=False)
+        buffer.publish(plan)
+        return plan
 
     def _execute(self, plan, t0, t1, rem, w, active, job_of_slot,
-                 completion, cut_after_completion=False) -> float:
+                 completion, cut_after_completion=False
+                 ) -> tuple[float, bool]:
         """Run [t0, t1) under ``plan``; mutate slot state in place.
 
         With ``cut_after_completion`` the segment stops at the first
         completion instead of running to t1 (the controller uses this
         when jobs are queued: a freed slot must be backfilled and
         replanned *at the completion time*, not at the next event).
-        Returns the time actually reached (t1, or the cut time).
+        Returns the time actually reached (t1, or the cut time) and
+        whether the window ran a second time to stop there.
         """
         M = self.M
         order = np.asarray(plan.order, np.int64)
@@ -337,21 +391,33 @@ class StreamController:
         live[:k] = active[order] & (rem[order] > 0)
         rem_rows = np.zeros(M)
         rem_rows[:k] = rem[order]
-        table = jnp.asarray(plan.table, jnp.result_type(float))
-        rem_j = jnp.asarray(rem_rows)
-        live_j = jnp.asarray(live)
-        rem_end, live_end, comp = _exec_window(
-            self.sp, table, rem_j, live_j, t1 - t0, self.rtol)
-        comp = np.asarray(comp)
+        with obs.span("repro.window.dispatch"):
+            obs.count("repro.h2d", self._window_h2d
+                      + (not isinstance(plan.table, jax.Array)))
+            table = jnp.asarray(plan.table, jnp.result_type(float))
+            rem_j = jnp.asarray(rem_rows)
+            live_j = jnp.asarray(live)
+            rem_end, live_end, comp = _exec_window(
+                self.sp, table, rem_j, live_j, t1 - t0, self.rtol)
+        with obs.span("repro.window.readback"):
+            obs.count("repro.d2h")
+            comp = np.asarray(comp)
         t_end = t1
+        cut = False
         if cut_after_completion and np.isfinite(comp).any():
             c0 = float(np.min(comp[np.isfinite(comp)]))
             if t0 + c0 < t1:
                 t_end = t0 + c0
-                rem_end, live_end, comp = _exec_window(
-                    self.sp, table, rem_j, live_j, c0, self.rtol)
-                comp = np.asarray(comp)
-        rem_end = np.asarray(rem_end)
+                cut = True
+                with obs.span("repro.window.cut"):
+                    obs.count("repro.h2d", 2)
+                    obs.count("repro.d2h")
+                    rem_end, live_end, comp = _exec_window(
+                        self.sp, table, rem_j, live_j, c0, self.rtol)
+                    comp = np.asarray(comp)
+        with obs.span("repro.window.readback"):
+            obs.count("repro.d2h")
+            rem_end = np.asarray(rem_end)
         freed = []
         for r in range(k):
             s = rows[r]
@@ -370,12 +436,17 @@ class StreamController:
             # and it must enter the order as an arrival, not inherit the
             # completed job's position
             self.policy.release(np.asarray(freed))
-        return t_end
+        return t_end, cut
 
     # -- interface --------------------------------------------------------
 
     def run(self, stream: ArrivalStream) -> StreamResult:
-        """Service the whole trace; see the module docstring."""
+        """Service the whole trace; see the module docstring.  One span
+        ``repro.loop``; each window is a ``repro.window``."""
+        with obs.span("repro.loop"):
+            return self._run(stream)
+
+    def _run(self, stream: ArrivalStream) -> StreamResult:
         N = len(stream)
         M = self.M
         x_all = np.asarray(stream.x, float)
@@ -402,9 +473,11 @@ class StreamController:
         self.policy.reset()
         B_live = self.B
         t_prev = 0.0
-        degraded = 0
-        replans = 0
         n_windows = 0
+        cut_reruns = 0
+        replans = degraded = 0
+        excess = 0.0
+        published: list[StreamPlan] = []     # not yet in ``excess``
 
         def fill_free_slots() -> bool:
             """Queued jobs into free slots (FIFO); True if any landed."""
@@ -418,6 +491,16 @@ class StreamController:
                 job_of_slot[s] = j
                 landed = True
             return landed
+
+        def replan(t) -> None:
+            nonlocal replans, degraded, excess
+            plan = self._replan(t, rem, wslot, active, B_live, buffer)
+            replans += 1
+            degraded += not plan.certified
+            published.append(plan)
+            if len(published) == _EXCESS_BATCH:
+                excess = _budget_excess(published, excess)
+                published.clear()
 
         for t_ev, kind, payload in events:
             # 1. execute up to this event on the front plan, splitting
@@ -435,15 +518,15 @@ class StreamController:
                 if plan is None or not active.any():
                     t_cur = t_stop
                     continue
-                t_end = self._execute(plan, t_cur, t_stop, rem, wslot,
-                                      active, job_of_slot, completion,
-                                      cut_after_completion=bool(queue))
+                with obs.span("repro.window"):
+                    t_end, cut = self._execute(
+                        plan, t_cur, t_stop, rem, wslot, active,
+                        job_of_slot, completion,
+                        cut_after_completion=bool(queue))
                 n_windows += 1
+                cut_reruns += cut
                 if t_end < t_stop and fill_free_slots():
-                    d, r = self._replan(t_end, rem, wslot, active,
-                                        B_live, buffer)
-                    degraded += d
-                    replans += r
+                    replan(t_end)
                 t_cur = t_end
             buffer.poll(t_ev)
             changed = fill_free_slots()
@@ -461,21 +544,22 @@ class StreamController:
                 break
             # 3. replan on the new state (double-buffered)
             if changed or buffer.front is None:
-                d, r = self._replan(t_ev, rem, wslot, active, B_live,
-                                    buffer)
-                degraded += d
-                replans += r
+                replan(t_ev)
             t_prev = t_ev
 
-        return self._finalize(stream, completion, admitted,
-                              replans=replans,
-                              warm_replans=self.policy.warm_replans,
-                              cold_replans=self.policy.cold_replans,
-                              degraded=degraded, n_windows=n_windows)
+        return self._finalize(
+            stream, completion, admitted, replans=replans,
+            warm_replans=self.policy.warm_replans,
+            cold_replans=self.policy.cold_replans, degraded=degraded,
+            n_windows=n_windows,
+            search_solves=getattr(self.policy, "search_solves", 0),
+            cut_reruns=cut_reruns,
+            budget_excess=_budget_excess(published, excess))
 
     def _finalize(self, stream, completion, admitted, *, replans,
-                  warm_replans, cold_replans, degraded,
-                  n_windows) -> StreamResult:
+                  warm_replans, cold_replans, degraded, n_windows,
+                  search_solves, cut_reruns,
+                  budget_excess) -> StreamResult:
         """SLO metrics from a completion array — shared verbatim by the
         host loop and the device scan so the two paths are compared on
         identical formulas."""
@@ -506,9 +590,11 @@ class StreamController:
         )
         return StreamResult(
             metrics=metrics, completion=completion, latency=lat,
-            slowdown=slow, admitted=admitted, replans=replans,
-            warm_replans=warm_replans, cold_replans=cold_replans,
-            degraded_windows=degraded, n_events=n_windows)
+            slowdown=slow, admitted=admitted, replans=int(replans),
+            warm_replans=int(warm_replans), cold_replans=int(cold_replans),
+            degraded_windows=int(degraded), n_windows=int(n_windows),
+            search_solves=int(search_solves), cut_reruns=int(cut_reruns),
+            budget_excess=float(budget_excess))
 
     def run_device(self, stream: ArrivalStream, *,
                    chunk_events: int | None = None) -> StreamResult:
@@ -531,11 +617,31 @@ class StreamController:
         logic that has no traced form here.  Cascade knobs (certificate
         rtol, solver sizes, search budget) are read off ``self.policy``
         when present so an oracle/device pair is configured once.
+
+        One span ``repro.scan`` with children ``.prepare`` (event
+        arrays, uploads, initial state, dispatch), ``.wait`` (the
+        device's run) and ``.finish`` (one read of the results, then the
+        metrics); the scan's work counters go to ``repro.scan.<name>``.
         """
         if self.admission is not None:
             raise ValueError(
                 "run_device supports admission=None only; scored "
                 "admission stays on the host loop")
+        with obs.span("repro.scan"):
+            with obs.span("repro.scan.prepare"):
+                state = self._dispatch_device(stream, chunk_events)
+            with obs.span("repro.scan.wait"):
+                state = jax.block_until_ready(state)
+            with obs.span("repro.scan.finish"):
+                N = len(stream)
+                counters, got = _read_scan(state)
+                completion = np.asarray(got["completion"][:N], float)
+                return self._finalize(stream, completion, np.ones(N, bool),
+                                      **counters)
+
+    def _dispatch_device(self, stream, chunk_events):
+        """``run_device``'s host side up to the last chunk's dispatch;
+        returns the final scan state (not waited for)."""
         N = len(stream)
         M = self.M
         dtype = jnp.result_type(float)
@@ -564,6 +670,10 @@ class StreamController:
         x_all = jnp.asarray(np.asarray(stream.x, float), dtype)
         w_all = jnp.asarray(np.asarray(stream.w, float), dtype)
         state = _stream_state0(M, N, self.B, dtype)
+        # per chunk: four event arrays, four scalars and the host leaves
+        # of the speedup and ladder; x and w once
+        obs.count("repro.h2d", 2 + n_chunks * (
+            8 + obs.host_leaves(self.sp, self.ladder)))
         for c in range(n_chunks):
             ev = tuple(jnp.asarray(a[c * W:(c + 1) * W])
                        for a in (t_e, kind, pi, pf))
@@ -578,15 +688,7 @@ class StreamController:
                 cap_iters=knobs["cap_iters"],
                 stol_rel=knobs["stol_rel"],
                 search_steps=knobs["search_steps"])
-        completion = np.asarray(state["completion"][:N], float)
-        admitted = np.ones(N, bool)
-        return self._finalize(
-            stream, completion, admitted,
-            replans=int(state["replans"]),
-            warm_replans=int(state["warm_ct"]),
-            cold_replans=int(state["cold_ct"]),
-            degraded=int(state["degraded"]),
-            n_windows=int(state["n_windows"]))
+        return state
 
 
 # ---------------------------------------------------------------------------
@@ -607,6 +709,29 @@ class StreamController:
 # cut_at_first_completion backfill, lowered into the scan as a
 # lax.cond around a re-run of the same `_exec_window` scan the host
 # calls), then apply the event and replan through the traced cascade.
+
+# the scan state's counters and the StreamResult fields they fill
+_SCAN_COUNTERS = {"replans": "replans", "warm_ct": "warm_replans",
+                  "cold_ct": "cold_replans", "degraded": "degraded",
+                  "n_windows": "n_windows", "search_solves": "search_solves",
+                  "cut_reruns": "cut_reruns",
+                  "budget_excess": "budget_excess"}
+
+
+def _read_scan(state: dict, keys=()) -> tuple[dict, dict]:
+    """One read of a finished scan (or a stack of them), the span
+    ``repro.scan.finish.read``: the counters as ``_finalize`` keywords,
+    and the state's ``completion`` and ``keys``.  The four work counters
+    also go to ``repro.scan.<name>``."""
+    with obs.span("repro.scan.finish.read"):
+        got = jax.device_get({k: state[k] for k in
+                              (*_SCAN_COUNTERS, "completion", *keys)})
+    obs.count("repro.d2h", len(got))
+    counters = {_SCAN_COUNTERS[k]: got.pop(k) for k in _SCAN_COUNTERS}
+    for k in ("replans", "n_windows", "search_solves", "cut_reruns"):
+        obs.count(f"repro.scan.{k}", int(np.sum(counters[k])))
+    return counters, got
+
 
 def _event_arrays(stream: ArrivalStream):
     """Merged device event arrays, ordered exactly like the host loop
@@ -654,7 +779,9 @@ def _stream_state0(M: int, N: int, B: float, dtype) -> dict:
         "degraded": jnp.zeros((), i32),
         "warm_ct": jnp.zeros((), i32),
         "cold_ct": jnp.zeros((), i32),
-        "searches": jnp.zeros((), i32),
+        "search_solves": jnp.zeros((), i32),
+        "cut_reruns": jnp.zeros((), i32),
+        "budget_excess": jnp.zeros((), dtype),
     }
 
 
@@ -695,7 +822,7 @@ def _replan_dev(s: dict, t_now, sp, ladder, B_key, plan_latency,
     """Traced _replan: cascade solve, publish to the back buffer
     (certified plans behind the solve latency, the ladder instantly)."""
     s = dict(s)
-    order, table, m, certified, searched, _, _, warm2 = (
+    order, table, m, certified, searched, _, _, warm2, sweeps = (
         stream_replan_core(sp, ladder, s["rem"], s["wslot"], s["active"],
                            s["B_live"], B_key, s["warm"], cert_rtol,
                            **knobs))
@@ -714,7 +841,9 @@ def _replan_dev(s: dict, t_now, sp, ladder, B_key, plan_latency,
                                             one, zero)
     s["cold_ct"] = s["cold_ct"] + jnp.where(searched | ~certified,
                                             one, zero)
-    s["searches"] = s["searches"] + jnp.where(searched, one, zero)
+    s["search_solves"] = s["search_solves"] + sweeps * (order.shape[0] - 1)
+    s["budget_excess"] = jnp.maximum(
+        s["budget_excess"], _plan_excess(table, m, s["B_live"]))
     return s
 
 
@@ -772,6 +901,8 @@ def _exec_until(s: dict, t_ev, sp, ladder, x_all, w_all, B_key,
             jnp.where(newly, -1, jobs_r))
         st["n_windows"] = st["n_windows"] + run.astype(
             st["n_windows"].dtype)
+        st["cut_reruns"] = st["cut_reruns"] + do_cut.astype(
+            st["cut_reruns"].dtype)
         st["t"] = t_end
         # backfill freed slots and replan at the cut time (the host's
         # "if t_end < t_stop and fill_free_slots()" branch)
@@ -824,6 +955,7 @@ def _stream_chunk(sp, ladder, state, events, x_all, w_all, B_key,
                   plan_latency, rtol, cert_rtol, *, fast, coarse,
                   descent_iters, cap_iters, stol_rel, search_steps):
     """One compiled dispatch servicing a chunk of events via lax.scan."""
+    obs.count("repro.retrace._stream_chunk")
     knobs = dict(fast=fast, coarse=coarse, descent_iters=descent_iters,
                  cap_iters=cap_iters, stol_rel=stol_rel,
                  search_steps=search_steps)

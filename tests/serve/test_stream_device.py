@@ -56,7 +56,11 @@ def _assert_parity(host, dev):
     assert host.warm_replans == dev.warm_replans
     assert host.cold_replans == dev.cold_replans
     assert host.degraded_windows == dev.degraded_windows
-    assert host.n_events == dev.n_events
+    assert host.n_windows == dev.n_windows
+    assert host.search_solves == dev.search_solves
+    assert host.cut_reruns == dev.cut_reruns
+    assert host.budget_excess == pytest.approx(dev.budget_excess,
+                                               abs=1e-9 * B)
     assert host.metrics == dev.metrics
 
 
@@ -85,6 +89,71 @@ def test_device_search_branch_exercised_and_identical():
     _assert_parity(host, dev)
 
 
+@pytest.mark.parametrize("seed,M,rate,weights", [
+    (5, 6, 0.25, "random"),      # non-agreeable: many searches
+    (3, 6, 0.15, "slowdown"),    # the day's weights: a few
+])
+def test_device_work_counters_match_host(seed, M, rate, weights):
+    # both tapes escalate to the exchange search and backfill at cut
+    # completions: the wasted-work counters must agree path for path
+    stream, ctl = _pair(seed, 1200.0, M, rate=rate, weights=weights)
+    host = ctl.run(stream)
+    dev = ctl.run_device(stream)
+    assert host.search_solves == dev.search_solves > 0
+    assert host.search_solves % (M - 1) == 0        # whole sweeps
+    assert host.cut_reruns == dev.cut_reruns > 0
+    assert host.cut_reruns <= host.n_windows
+    # plans spend the live budget and no more, to rounding
+    tol = 64 * np.finfo(float).eps * B
+    assert 0.0 <= host.budget_excess <= tol
+    assert 0.0 <= dev.budget_excess <= tol
+
+
+class _Inflated:
+    """``StreamCascadePolicy`` whose every table asks for 10% too much."""
+
+    def __init__(self, inner):
+        self.inner = inner
+
+    def plan(self, rem, w, active=None, B=None):
+        import dataclasses
+        p = self.inner.plan(rem, w, active, B)
+        return dataclasses.replace(p, table=p.table * 1.1)
+
+    def release(self, slots):
+        self.inner.release(slots)
+
+    def reset(self):
+        self.inner.reset()
+
+    @property
+    def warm_replans(self):
+        return self.inner.warm_replans
+
+    @property
+    def cold_replans(self):
+        return self.inner.cold_replans
+
+
+def test_budget_excess_flags_an_inflated_table():
+    from repro.serve.stream import _plan_excess
+    stream, ctl = _pair(3, 600.0, 6, rate=0.15)
+    ok = ctl.run(stream)
+    ctl.policy = _Inflated(ctl.policy)
+    bad = ctl.run(stream)
+    assert ok.budget_excess <= 64 * np.finfo(float).eps * B
+    # a full live column sums to B(t) <= B, so 10% over reads up to 0.1 B
+    assert 0.0 < bad.budget_excess <= 0.1 * B * (1 + 1e-9)
+    # the scan's traced form on one such table
+    plan = StreamCascadePolicy(SP, B).plan(np.array([3.0, 2.0, 1.0, 0.0]),
+                                           np.ones(4), B=B)
+    assert float(_plan_excess(plan.table, plan.m, B)) == pytest.approx(
+        0.0, abs=1e-9)
+    assert float(_plan_excess(plan.table * 1.1, plan.m, B)) == (
+        pytest.approx(0.1 * B))
+    assert float(_plan_excess(plan.table * 1.1, 0, B)) == 0.0
+
+
 def test_device_chunked_equals_single_dispatch():
     # chunk_events splits the trace into several compiled dispatches
     # with the carry handed across — the seam must be invisible
@@ -93,7 +162,7 @@ def test_device_chunked_equals_single_dispatch():
     chunked = ctl.run_device(stream, chunk_events=17)
     np.testing.assert_array_equal(whole.completion, chunked.completion)
     assert whole.replans == chunked.replans
-    assert whole.n_events == chunked.n_events
+    assert whole.n_windows == chunked.n_windows
 
 
 def test_device_rejects_scored_admission():
