@@ -21,18 +21,22 @@ Span names start with ``repro.``; the control plane's are
   ``repro.loop``    one ``StreamController.run`` (self: event sorting,
                     queue and slot bookkeeping, ``_finalize``);
   ``repro.plan``    one ``StreamCascadePolicy.plan``, with children
-                    ``.dispatch`` (uploads and the cascade call) and
-                    ``.readback`` (the decision's reads);
+                    ``.dispatch`` (the live state's one packed upload and
+                    the cascade call) and ``.readback`` (the decision's
+                    one packed read);
   ``repro.window``  one ``StreamController._execute``, with children
-                    ``.dispatch``, ``.readback`` and ``.cut`` (the re-run
-                    at a first completion and its read);
+                    ``.dispatch`` (one packed upload and the call),
+                    ``.readback`` (one packed read) and ``.cut`` (the
+                    re-run at a first completion: one upload, one read);
   ``repro.scan``    one ``run_device`` or ``serve_streams_sharded``, with
                     children ``.prepare`` (host work before the device
                     runs, the dispatch included), ``.wait`` (blocked on
                     the device) and ``.finish`` (the one read,
                     ``.finish.read``, then the metrics).
 
-Counters: ``repro.h2d`` and ``repro.d2h`` (arrays moved each way),
+Counters: ``repro.h2d`` and ``repro.d2h`` (arrays moved each way: on
+the served loop one and one per plan, per window and per cut, the fixed
+arguments having gone up once when the policy and controller were built),
 ``repro.scan.<counter>`` (the event scan's own work counts) and
 ``repro.retrace.<function>`` (traces of a jitted function).
 """

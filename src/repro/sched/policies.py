@@ -57,6 +57,7 @@ empty active set (θ ≡ 0), which the engine's halt steps rely on.
 from __future__ import annotations
 
 import dataclasses
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -910,10 +911,40 @@ def stream_warm0(M: int, dtype=None) -> WarmStart:
                            jnp.asarray(fi.max, dtype) / 4.0]))
 
 
-_cascade_call = jax.jit(
-    stream_replan_core,
-    static_argnames=("fast", "coarse", "descent_iters", "cap_iters",
-                     "stol_rel", "search_steps"))
+@partial(jax.jit, static_argnames=("fast", "coarse", "descent_iters",
+                                   "cap_iters", "stol_rel", "search_steps"))
+def _cascade_packed(sp, ladder, packed, B_key, warm, certificate_rtol, *,
+                    fast, coarse, descent_iters, cap_iters, stol_rel,
+                    search_steps):
+    """``stream_replan_core`` with one input and one output for the host.
+
+    ``packed`` is the (3M+1,) float vector ``[rem, w, active, B_live]``
+    (``active`` as 0/1).  Returns ``(out, table, warm2)``: ``out`` is the
+    int32 vector ``[order (M), m, certified, searched, sweeps, J,
+    J_linear]``, the two floats bitcast to int32 (one int32 each in
+    float32, two in float64), so the host reads every output it needs in
+    one copy; ``table`` and ``warm2`` stay on the device.
+    """
+    obs.count("repro.retrace._cascade_packed")
+    M = (packed.shape[0] - 1) // 3
+    (order, table, m, certified, searched, J, J_lin, warm2,
+     sweeps) = stream_replan_core(
+        sp, ladder, packed[:M], packed[M:2 * M], packed[2 * M:3 * M] != 0,
+        packed[3 * M], B_key, warm, certificate_rtol, fast=fast,
+        coarse=coarse, descent_iters=descent_iters, cap_iters=cap_iters,
+        stol_rel=stol_rel, search_steps=search_steps)
+    flags = jnp.stack([m, certified, searched, sweeps]).astype(jnp.int32)
+    Js = jax.lax.bitcast_convert_type(jnp.stack([J, J_lin]), jnp.int32)
+    return jnp.concatenate([order, flags, Js.reshape(-1)]), table, warm2
+
+
+def _read_decision(out, M: int, dtype):
+    """``_cascade_packed``'s ``out``, read back as NumPy, unpacked:
+    ``(order, m, certified, searched, sweeps, J, J_linear)``."""
+    m, certified, searched, sweeps = (int(v) for v in out[M:M + 4])
+    J, J_lin = (float(v) for v in out[M + 4:].view(dtype))
+    return (out[:M].astype(np.int64), m, bool(certified), bool(searched),
+            sweeps, J, J_lin)
 
 
 class StreamCascadePolicy:
@@ -936,9 +967,13 @@ class StreamCascadePolicy:
     ladder), ``order_searches`` counts search entries and
     ``search_solves`` the exchange search's solves (sweeps × (M−1)).
 
-    Each ``plan`` is the span ``repro.plan``: ``repro.plan.dispatch``
-    (uploads and the cascade call) and ``repro.plan.readback`` (the
-    reads the decision needs), counted in ``repro.h2d``/``repro.d2h``.
+    Transfers: the speedup, the ladder, the nominal budget and the
+    certificate tolerance go to the device once, at construction, and
+    the warm payload and plan table stay there.  Each ``plan`` is the
+    span ``repro.plan``: ``repro.plan.dispatch`` uploads the live state
+    as one packed array and calls ``_cascade_packed``;
+    ``repro.plan.readback`` reads the decision as one packed array.  One
+    each way, counted in ``repro.h2d``/``repro.d2h``.
     """
 
     device_ready = False
@@ -967,9 +1002,10 @@ class StreamCascadePolicy:
             from repro.robust.degrade import DegradingPolicy
             ladder = DegradingPolicy.ladder(self.sp, B=self.B)
         self.ladder = ladder
-        # rem, w, active and three scalars, with the host leaves of the
-        # speedup and ladder: what each cascade call uploads
-        self._h2d = 6 + obs.host_leaves(self.sp, self.ladder)
+        # the cascade call's fixed arguments, uploaded once
+        self._fixed = jax.device_put((self.sp, self.ladder, self.B,
+                                      self.certificate_rtol))
+        self._warm0: WarmStart | None = None     # built once per M
         self.reset()
 
     def reset(self) -> None:
@@ -985,41 +1021,39 @@ class StreamCascadePolicy:
     def plan(self, rem, w, active=None, B=None) -> StreamPlan:
         with obs.span("repro.plan"):
             rem = np.asarray(rem, float)
-            w = np.asarray(w, float)
             M = rem.shape[0]
-            act = (np.ones(M, bool) if active is None
-                   else np.asarray(active, bool))
-            Bv = float(self.B if B is None else B)
             dtype = jnp.result_type(float)
+            packed = np.empty(3 * M + 1, dtype)
+            packed[:M] = rem
+            packed[M:2 * M] = w
+            packed[2 * M:3 * M] = (True if active is None
+                                   else np.asarray(active, bool))
+            Bv = float(self.B if B is None else B)
+            packed[3 * M] = Bv
             if self.warm is None or self.warm.lam.shape != (M,):
-                self.warm = stream_warm0(M, dtype)
+                if self._warm0 is None or self._warm0.lam.shape != (M,):
+                    self._warm0 = stream_warm0(M, dtype)
+                self.warm = self._warm0
             steps = (4 * M if self.search_steps is None
                      else int(self.search_steps))
+            sp, ladder, B_key, cert_rtol = self._fixed
             with obs.span("repro.plan.dispatch"):
-                obs.count("repro.h2d", self._h2d)
-                (order, table, m_, certified, searched, J, J_lin, warm2,
-                 sweeps) = _cascade_call(
-                    self.sp, self.ladder, jnp.asarray(rem, dtype),
-                    jnp.asarray(w, dtype), jnp.asarray(act), Bv, self.B,
-                    self.warm, self.certificate_rtol, fast=self._fast,
-                    coarse=self.coarse, descent_iters=self.descent_iters,
+                obs.count("repro.h2d")
+                out, table, self.warm = _cascade_packed(
+                    sp, ladder, jax.device_put(packed), B_key, self.warm,
+                    cert_rtol, fast=self._fast, coarse=self.coarse,
+                    descent_iters=self.descent_iters,
                     cap_iters=self.cap_iters, stol_rel=self.stol_rel,
                     search_steps=steps)
-            self.warm = WarmStart(lam=warm2.lam, bracket=warm2.bracket)
             with obs.span("repro.plan.readback"):
-                m = int(m_)
-                cert = bool(certified)
-                sd = bool(searched)
-                order = np.asarray(order, np.int64)
-                J = float(J)
-                J_lin = float(J_lin)
-                # the sweep count is read only when the search ran
-                solves = int(sweeps) * (M - 1) if sd else 0
-                obs.count("repro.d2h", 6 + sd)
+                obs.count("repro.d2h")
+                out = jax.device_get(out)
+            order, m, cert, sd, sweeps, J, J_lin = _read_decision(
+                out, M, dtype)
             self.warm_replans += int(cert and not sd)
             self.cold_replans += int(sd or not cert)
             self.order_searches += int(sd)
-            self.search_solves += solves
+            self.search_solves += sweeps * (M - 1)
             return StreamPlan(order=order[:m], table=table, J=J,
                               J_linear=J_lin, m=m, B=Bv,
                               warm=cert and not sd, certified=cert)

@@ -148,6 +148,21 @@ def _exec_window(sp, table, rem0, live0, span, rtol):
         return rem, live, comp
 
 
+@jax.jit
+def _window_packed(sp, table, packed, rtol):
+    """``_exec_window`` with one input and one output for the host.
+
+    ``packed`` is the (2M+1,) float vector ``[rem0, live0, span]``
+    (``live0`` as 0/1); returns ``[comp, rem_end]``, shape (2M,).
+    """
+    obs.count("repro.retrace._window_packed")
+    M = (packed.shape[0] - 1) // 2
+    rem_end, _, comp = _exec_window(sp, table, packed[:M],
+                                    packed[M:2 * M] != 0, packed[2 * M],
+                                    rtol)
+    return jnp.concatenate([comp, rem_end])
+
+
 def _plan_excess(table, m, B_live):
     """How far the plan's current column (m−1), summed over its m live
     rows, exceeds the live budget; traced."""
@@ -181,7 +196,8 @@ def _budget_excess(plans, excess: float = 0.0) -> float:
     B[:n] = [p.B for p in plans]
     obs.count("repro.h2d", 2 + obs.host_leaves(tables))
     obs.count("repro.d2h")
-    return max(excess, float(_excess_batch(tuple(tables), m, B)))
+    return max(excess, float(_excess_batch(tuple(tables),
+                                           *jax.device_put((m, B)))))
 
 
 # ---------------------------------------------------------------------------
@@ -325,9 +341,8 @@ class StreamController:
                        if ladder is None else ladder)
         self.plan_latency = float(plan_latency)
         self.rtol = float(rtol)
-        # rem, live, span and rtol, with the speedup's host leaves: what
-        # each window call uploads besides a host-built table
-        self._window_h2d = 4 + obs.host_leaves(self.sp)
+        # the window call's fixed arguments, uploaded once
+        self._window_fixed = jax.device_put((self.sp, self.rtol))
 
     # -- internals --------------------------------------------------------
 
@@ -381,6 +396,13 @@ class StreamController:
         replanned *at the completion time*, not at the next event).
         Returns the time actually reached (t1, or the cut time) and
         whether the window ran a second time to stop there.
+
+        Transfers: the speedup and ``rtol`` are on the device from
+        construction and a cascade plan's table already is; each call of
+        ``_window_packed`` uploads ``[rem, live, span]`` as one array and
+        reads ``[comp, rem_end]`` as one (``repro.window.dispatch``,
+        ``.readback``), and a cut runs it once more with span c0
+        (``.cut``).  A ladder plan's host-built table is uploaded too.
         """
         M = self.M
         order = np.asarray(plan.order, np.int64)
@@ -389,35 +411,35 @@ class StreamController:
         rows[:k] = order
         live = np.zeros(M, bool)
         live[:k] = active[order] & (rem[order] > 0)
-        rem_rows = np.zeros(M)
-        rem_rows[:k] = rem[order]
+        dtype = jnp.result_type(float)
+        packed = np.zeros(2 * M + 1, dtype)
+        packed[:k] = rem[order]
+        packed[M:2 * M] = live
+        packed[2 * M] = t1 - t0
+        sp, rtol = self._window_fixed
         with obs.span("repro.window.dispatch"):
-            obs.count("repro.h2d", self._window_h2d
-                      + (not isinstance(plan.table, jax.Array)))
-            table = jnp.asarray(plan.table, jnp.result_type(float))
-            rem_j = jnp.asarray(rem_rows)
-            live_j = jnp.asarray(live)
-            rem_end, live_end, comp = _exec_window(
-                self.sp, table, rem_j, live_j, t1 - t0, self.rtol)
+            obs.count("repro.h2d", 1 + (not isinstance(plan.table,
+                                                       jax.Array)))
+            table = jnp.asarray(plan.table, dtype)
+            out = _window_packed(sp, table, jax.device_put(packed), rtol)
         with obs.span("repro.window.readback"):
             obs.count("repro.d2h")
-            comp = np.asarray(comp)
+            out = jax.device_get(out)
         t_end = t1
         cut = False
+        comp = out[:M]
         if cut_after_completion and np.isfinite(comp).any():
             c0 = float(np.min(comp[np.isfinite(comp)]))
             if t0 + c0 < t1:
                 t_end = t0 + c0
                 cut = True
+                packed[2 * M] = c0
                 with obs.span("repro.window.cut"):
-                    obs.count("repro.h2d", 2)
+                    obs.count("repro.h2d")
                     obs.count("repro.d2h")
-                    rem_end, live_end, comp = _exec_window(
-                        self.sp, table, rem_j, live_j, c0, self.rtol)
-                    comp = np.asarray(comp)
-        with obs.span("repro.window.readback"):
-            obs.count("repro.d2h")
-            rem_end = np.asarray(rem_end)
+                    out = jax.device_get(_window_packed(
+                        sp, table, jax.device_put(packed), rtol))
+        comp, rem_end = out[:M], out[M:]
         freed = []
         for r in range(k):
             s = rows[r]

@@ -19,14 +19,17 @@ import json
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from repro.core import power, sample_arrival_stream
 from repro.core.workloads import (ArrivalStream, arrival_stream_from_log,
                                   load_arrival_log)
-from repro.sched.policies import StreamingSmartFillPolicy
+from repro.sched.policies import (StreamingSmartFillPolicy, _cascade_packed,
+                                  _read_decision, stream_replan_core,
+                                  stream_warm0)
 from repro.serve import PlanBuffer, StreamCascadePolicy, StreamController
-from repro.serve.stream import _exec_window, _rate_floor
+from repro.serve.stream import _exec_window, _rate_floor, _window_packed
 
 B = 10.0
 SP = power(1.0, 0.5, B)
@@ -186,6 +189,108 @@ def test_device_day_trace_parity():
     dev = ctl.run_device(stream)
     assert host.cold_replans > 0 and host.degraded_windows > 0
     _assert_parity(host, dev)
+
+
+# ---------------------------------------------------------------------------
+# The host's packed entries: one upload and one read, the same bits
+# ---------------------------------------------------------------------------
+
+_STATIC = ("fast", "coarse", "descent_iters", "cap_iters", "stol_rel",
+           "search_steps")
+_cascade_call = jax.jit(stream_replan_core, static_argnames=_STATIC)
+
+
+def _live_state(stage):
+    """Six slots, one idle, under a dipped budget: agreeable weights
+    certify the fresh solve; seed 1's random weights escalate to the
+    exchange search, and with no search step allowed reach the ladder."""
+    r = np.random.default_rng(0 if stage == "fresh" else 1)
+    rem = r.uniform(0.5, 20.0, 6)
+    w = 1.0 / rem if stage == "fresh" else r.uniform(0.1, 5.0, 6)
+    active = np.ones(6, bool)
+    active[5] = stage == "fresh"
+    active[4] = False
+    return rem, w, active, 7.0, (0 if stage == "ladder" else 24)
+
+
+def _assert_same_bits(a, b):
+    a, b = np.asarray(a), np.asarray(b)
+    assert a.dtype == b.dtype and a.shape == b.shape
+    np.testing.assert_array_equal(a.view(np.uint8), b.view(np.uint8))
+
+
+@pytest.mark.parametrize("x64", [True, False], ids=["f64", "f32"])
+@pytest.mark.parametrize("stage", ["fresh", "search", "ladder"])
+def test_packed_cascade_matches_cascade_call_bit_for_bit(stage, x64):
+    rem, w, active, B_live, steps = _live_state(stage)
+    with jax.enable_x64(x64):
+        sp = power(1.0, 0.5, B)
+        pol = StreamCascadePolicy(sp, B, search_steps=steps)
+        dtype = jnp.result_type(float)
+        static = dict(fast=pol._fast, coarse=pol.coarse,
+                      descent_iters=pol.descent_iters,
+                      cap_iters=pol.cap_iters, stol_rel=pol.stol_rel,
+                      search_steps=steps)
+        sp_d, ladder_d, B_key, cert_rtol = pol._fixed
+        warm = stream_warm0(6, dtype)
+        # a fresh payload, then the payload the first call left
+        for shrink in (1.0, 0.9):
+            x = rem * shrink
+            ref = _cascade_call(
+                pol.sp, pol.ladder, jnp.asarray(x, dtype),
+                jnp.asarray(w, dtype), jnp.asarray(active), B_live, B,
+                warm, pol.certificate_rtol, **static)
+            packed = np.concatenate([x, w, active, [B_live]]).astype(dtype)
+            out, table, warm2 = _cascade_packed(
+                sp_d, ladder_d, jax.device_put(packed), B_key, warm,
+                cert_rtol, **static)
+            order, m, cert, searched, sweeps, J, J_lin = _read_decision(
+                jax.device_get(out), 6, dtype)
+            np.testing.assert_array_equal(order, np.asarray(ref[0]))
+            assert (m, cert, searched, sweeps) == (
+                int(ref[2]), bool(ref[3]), bool(ref[4]), int(ref[8]))
+            _assert_same_bits(np.array([J, J_lin], dtype),
+                              np.array([ref[5], ref[6]]))
+            _assert_same_bits(table, ref[1])
+            _assert_same_bits(warm2.lam, ref[7].lam)
+            _assert_same_bits(warm2.bracket, ref[7].bracket)
+            warm = ref[7]
+    # each state reaches the stage it stands for
+    assert cert == (stage != "ladder")
+    assert searched == (stage != "fresh")
+    assert (sweeps > 0) == (stage == "search")
+
+
+@pytest.mark.parametrize("x64", [True, False], ids=["f64", "f32"])
+def test_packed_window_matches_exec_window_bit_for_bit(x64):
+    M = 5
+    with jax.enable_x64(x64):
+        sp = power(1.0, 0.5, B)
+        dtype = jnp.result_type(float)
+        pol = StreamCascadePolicy(sp, B)
+        rem = np.array([4.0, 1.5, 7.0, 0.0, 0.0])
+        live = np.array([True, True, True, False, False])
+        table = pol.plan(rem, 1.0 / np.maximum(rem, 1.0), live).table
+        ctl = StreamController(sp, B, max_live=M)
+        sp_d, rtol = ctl._window_fixed
+        packed = np.concatenate([rem, live, [30.0]]).astype(dtype)
+        got = jax.device_get(_window_packed(
+            sp_d, table, jax.device_put(packed), rtol))
+        rem_end, _, comp = _exec_window(ctl.sp, table, jnp.asarray(rem),
+                                        jnp.asarray(live), 30.0, ctl.rtol)
+        _assert_same_bits(got[:M], comp)
+        _assert_same_bits(got[M:], rem_end)
+        # the cut re-run: the same window stopped at its first completion
+        c0 = float(np.min(got[:M][np.isfinite(got[:M])]))
+        assert 0.0 < c0 < 30.0
+        packed[2 * M] = c0
+        got = jax.device_get(_window_packed(
+            sp_d, table, jax.device_put(packed), rtol))
+        rem_end, _, comp = _exec_window(ctl.sp, table, jnp.asarray(rem),
+                                        jnp.asarray(live), c0, ctl.rtol)
+        _assert_same_bits(got[:M], comp)
+        _assert_same_bits(got[M:], rem_end)
+        assert np.isfinite(got[:M]).sum() == 1
 
 
 # ---------------------------------------------------------------------------

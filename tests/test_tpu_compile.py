@@ -142,3 +142,31 @@ def test_fmath_lowers_in_a_kernel(one_chip):
 
     compiled = _compile(one_chip, fn, np.ones((8, 128), np.float32))
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_served_loop_entries_compile(one_chip):
+    # the host loop's two device calls, at the served cell's 16 slots:
+    # one packed input and one packed output each
+    from repro.core import power
+    from repro.sched.policies import _cascade_packed, stream_warm0
+    from repro.serve import StreamCascadePolicy
+    from repro.serve.stream import _window_packed
+
+    M = 16
+    f = np.float32
+    with jax.enable_x64(False):
+        sp = power(1.0, 0.5, B)
+        pol = StreamCascadePolicy(sp, B)
+        warm = stream_warm0(M, jnp.float32)
+    plan = _compile(
+        one_chip, _cascade_packed, pol.sp, pol.ladder,
+        np.zeros(3 * M + 1, f), f(B), warm, f(1e-8), fast=pol._fast,
+        coarse=32, descent_iters=40, cap_iters=64, stol_rel=None,
+        search_steps=4 * M)
+    out, table, _ = plan.out_info
+    assert (out.shape, out.dtype) == ((M + 6,), jnp.int32)
+    assert table.shape == (M, M)
+    window = _compile(one_chip, _window_packed, pol.sp, np.zeros((M, M), f),
+                      np.zeros(2 * M + 1, f), f(1e-12))
+    assert (window.out_info.shape, window.out_info.dtype) == (
+        (2 * M,), jnp.float32)
